@@ -53,7 +53,7 @@ def main():
     print(f"qdist_{cq}x{cc}x{d},{1e6*t:.0f},{1e6*nbytes/HBM_BW:.0f},{nbytes}")
 
     # interpret-mode correctness spot check (kernels vs oracle) at bench shapes
-    got = hamming_matrix(a[:8], b[:256], use_kernel=True, interpret=True)
+    got = hamming_matrix(a[:8], b[:256], use_kernel=True)
     ref = hamming_matrix(a[:8], b[:256])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     print("kernel_interpret_check,ok,,")

@@ -35,6 +35,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = sys.argv[1:] or ["kernels", "hsort", "phases", "table2", "table1",
                              "churn", "search", "sharded", "sharded_churn",
                              "serving", "durability"]

@@ -73,7 +73,11 @@ _WORKER_ENV = "_SERVING_BENCH_WORKER"
 
 
 def main(smoke: bool = False) -> dict:
-    if os.environ.get(_WORKER_ENV) != "1":
+    # Re-exec only on the CPU, for virtual devices.  On an accelerator this
+    # process holds the chip, so the worker runs here.
+    import jax
+
+    if os.environ.get(_WORKER_ENV) != "1" and jax.default_backend() == "cpu":
         env = dict(os.environ)
         env[_WORKER_ENV] = "1"
         if not smoke:  # smoke runs the single-device mutable layout

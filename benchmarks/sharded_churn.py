@@ -31,7 +31,11 @@ _WORKER_ENV = "_SHARDED_CHURN_BENCH_WORKER"
 
 
 def main(smoke: bool = False) -> dict:
-    if os.environ.get(_WORKER_ENV) != "1":
+    # Re-exec only on the CPU, for virtual devices.  On an accelerator this
+    # process holds the chip, so the worker runs here.
+    import jax
+
+    if os.environ.get(_WORKER_ENV) != "1" and jax.default_backend() == "cpu":
         env = dict(os.environ)
         env[_WORKER_ENV] = "1"
         env["XLA_FLAGS"] = (

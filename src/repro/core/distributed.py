@@ -30,9 +30,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import hilbert, quantize, sketch
 from repro.core.types import ForestConfig, GraphParams
@@ -43,11 +42,6 @@ _MAXU = jnp.uint32(0xFFFFFFFF)
 # ---------------------------------------------------------------------------
 # Sample sort (shard_map core)
 # ---------------------------------------------------------------------------
-
-
-def _local_lexsort(keys: jax.Array) -> jax.Array:
-    w = keys.shape[1]
-    return jnp.lexsort(tuple(keys[:, i] for i in range(w - 1, -1, -1)))
 
 
 def _bucket_of(splitters: jax.Array, keys_sorted: jax.Array) -> jax.Array:
@@ -96,7 +90,7 @@ def sample_sort_sharded(
         payload_d = dict(zip(names, payload_l))
         ln = keys_l.shape[0]
 
-        order = _local_lexsort(keys_l)
+        order = hilbert.lexsort_words(keys_l)
         keys_s = keys_l[order]
         pay_s = {k: v[order] for k, v in payload_d.items()}
 
@@ -106,7 +100,7 @@ def sample_sort_sharded(
         cand = keys_s[samp_idx]                       # (s, W)
         allc = lax.all_gather(cand, axis)             # (p, s, W)
         flat = allc.reshape(p * s, w)
-        flat = flat[_local_lexsort(flat)]
+        flat = flat[hilbert.lexsort_words(flat)]
         split_idx = (jnp.arange(1, p) * s).astype(jnp.int32)
         splitters = flat[split_idx - 1]               # (p-1, W)
 
@@ -140,7 +134,7 @@ def sample_sort_sharded(
             recv_pay[kname] = rv
 
         # --- local merge; sentinels (MAXU keys) sort to the tail ---
-        morder = _local_lexsort(recv_keys)
+        morder = hilbert.lexsort_words(recv_keys)
         keys_o = recv_keys[morder]
         pay_o = {k: v[morder] for k, v in recv_pay.items()}
         is_valid = ~jnp.all(keys_o == _MAXU, axis=1)
@@ -153,7 +147,7 @@ def sample_sort_sharded(
         (P(axis),) + tuple(P(axis) for _ in payload) + (P(axis), P(axis))
     )
     fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+                   check_vma=False)
     outs = fn(keys, *payload.values())
     keys_out = outs[0]
     pay_out = dict(zip(payload.keys(), outs[1 : 1 + len(payload)]))
@@ -181,10 +175,14 @@ def distributed_hilbert_order(
 ):
     """Global Hilbert ordering of sharded points (+payload), sample-sorted."""
     n = points.shape[0]
-    keys = hilbert.hilbert_keys(
-        points, bits=cfg.bits, key_bits=cfg.key_bits, lo=lo, hi=hi,
-        perm=perm, flip=flip,
-    )
+    # Each device keys its own rows: the key pass maps over row blocks,
+    # which on the global array would gather every block to every device.
+    keys = shard_map(
+        lambda p: hilbert.hilbert_keys(
+            p, bits=cfg.bits, key_bits=cfg.key_bits, lo=lo, hi=hi,
+            perm=perm, flip=flip),
+        mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None),
+    )(points)
     gids = jnp.arange(n, dtype=jnp.int32)
     pay = {"gid": gids}
     if payload:
@@ -225,8 +223,10 @@ def hilbert_partition(
     if n_shards is None:
         n_shards = p
     n = points.shape[0]
-    lo = jnp.min(points, axis=0)
-    hi = jnp.max(points, axis=0)
+    # ``points`` may be a host array: bounds are exact either way, and the
+    # sample sort then uploads each device's slice straight to its device.
+    lo = jnp.asarray(np.min(points, axis=0))
+    hi = jnp.asarray(np.max(points, axis=0))
     order = None
     if p > 1 and n % p == 0:
         pts_sh = jax.device_put(points, NamedSharding(mesh, P(axis, None)))
@@ -412,7 +412,7 @@ def halo_window_candidates(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(gids_sorted, sketches_sorted, n_valid)
 
@@ -469,7 +469,7 @@ def route_home(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(owner_gid, cand_g, cand_d)
 
@@ -599,7 +599,7 @@ def tree_merge_topk(ids, d2, *, k: int, axis: str, axis_size: int,
     the lower rank's block first (``merge_topk_pair`` keyed on
     ``(rank & s) == 0``) — so by induction every rank holds bit-identical
     partials after every hop, and the final (Q, k) is safe to declare
-    replicated (``out_specs P(None)``) even with ``check_rep=False``.
+    replicated (``out_specs P(None)``) even with ``check_vma=False``.
 
     ``prune=True`` adds one ``lax.pmin`` of each rank's local kth-best
     distance before the first hop and masks local candidates strictly

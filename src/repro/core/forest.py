@@ -58,7 +58,6 @@ def forest_randomization(cfg: ForestConfig, d: int) -> Tuple[np.ndarray, np.ndar
     return perms, flips
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "key_bits", "leaf_size"))
 def _build_tree(points, lo, hi, perm, flip, *, bits, key_bits, leaf_size):
     order, sorted_keys = hilbert.hilbert_sort(
         points, bits=bits, key_bits=key_bits, lo=lo, hi=hi, perm=perm, flip=flip

@@ -9,7 +9,7 @@ compute, per point, a **truncated Hilbert key**: the top ``key_bits`` bits of
 the Hilbert index, via Skilling's transform ("Programming the Hilbert curve",
 AIP Conf. Proc. 707, 2004).  Skilling's transform is O(d·b) identical bit-ops
 per point — perfectly data-parallel over n points (VPU-friendly) — and the
-truncated keys are sorted lexicographically with ``jnp.lexsort``.
+truncated keys are sorted lexicographically (:func:`lexsort_words`).
 
 Key layout: a key is ``W = ceil(key_bits/32)`` uint32 words, word 0 most
 significant, bit 31 of word 0 the most significant bit.  The Hilbert index bit
@@ -33,6 +33,7 @@ __all__ = [
     "quantize_points",
     "hilbert_keys",
     "hilbert_sort",
+    "lexsort_words",
     "lex_less",
     "lex_searchsorted",
     "key_words",
@@ -49,97 +50,71 @@ def key_words(key_bits: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _level_pass(x: jax.Array, level: int, reverse: bool) -> jax.Array:
-    """One level of Skilling's "inverse undo", without a sequential scan.
-
-    Skilling's per-level loop threads a carry register through the dims:
-      i == 0:  if X[0] & Q: X[0] ^= P                     (invert register)
-      i >= 1:  if X[i] & Q: carry ^= P                    (invert register)
-               else:        swap P-masked low bits of carry and X[i]
-    (the else-branch algebra: t=(c^Xi)&P; c^=t; Xi^=t  ==  an exact swap of
-    the low P bits).  Because each step either *inverts* the register or
-    *swaps* it with a column, the value any column receives is the low bits
-    of the **previous swap column** (or the initial register), XOR'd by P if
-    the number of intervening inverts is odd.  That is a cummax (previous
-    swap index) + cumsum (invert parity) + gather — fully data-parallel.
-    ``reverse=True`` runs the involution backwards (dims d-1..1, then the
-    i==0 op), which is the inverse pass used by :func:`transpose_to_axes`.
-
-    Note: a straightforward ``lax.scan`` formulation is miscompiled by
-    XLA:CPU at batch >= 32 (carry vectorization bug, jax 0.8.2); this
-    formulation is also asymptotically better (O(log d) depth on TPU).
-    """
-    n, d = x.shape
-    q = jnp.uint32(1 << level)
-    p = jnp.uint32((1 << level) - 1)
-    np_ = jnp.uint32(~((1 << level) - 1) & 0xFFFFFFFF)
-
-    x0 = x[:, 0]
-    cond0 = (x0 & q) != 0
-    if d == 1:
-        return jnp.where(cond0, x0 ^ p, x0)[:, None]
-
-    body = x[:, 1:]
-    if reverse:
-        body = body[:, ::-1]
-
-    cond = (body & q) != 0          # invert ops           (n, d-1)
-    swap = ~cond                    # swap ops
-    inv = cond.astype(jnp.int32)
-    s_excl = jnp.cumsum(inv, axis=1) - inv          # inverts before t
-    total = jnp.sum(inv, axis=1)                    # (n,)
-    if not reverse:
-        # forward: the i==0 self-invert happens before everything
-        s_excl = s_excl + cond0.astype(jnp.int32)[:, None]
-        total = total + cond0.astype(jnp.int32)
-
-    tpos = jnp.broadcast_to(jnp.arange(d - 1, dtype=jnp.int32)[None, :], (n, d - 1))
-    swap_pos = jnp.where(swap, tpos, jnp.int32(-1))
-    run_max = lax.cummax(swap_pos, axis=1)
-    prev = jnp.concatenate(
-        [jnp.full((n, 1), -1, jnp.int32), run_max[:, :-1]], axis=1
-    )  # previous swap strictly before t
-
-    src_gather = jnp.take_along_axis(body, jnp.maximum(prev, 0).astype(jnp.int32), axis=1)
-    src_low = jnp.where(prev < 0, x0[:, None], src_gather) & p
-    s_at_prev = jnp.take_along_axis(s_excl, jnp.maximum(prev, 0).astype(jnp.int32), axis=1)
-    s_j = jnp.where(prev < 0, 0, s_at_prev)
-    parity = ((s_excl - s_j) & 1) == 1
-    new_low = jnp.where(parity, src_low ^ p, src_low)
-    body_new = jnp.where(swap, (body & np_) | new_low, body)
-
-    # final register -> column 0
-    last_swap = run_max[:, -1]                     # (n,)
-    v_gather = jnp.take_along_axis(
-        body, jnp.maximum(last_swap, 0)[:, None].astype(jnp.int32), axis=1
-    )[:, 0]
-    v_src = jnp.where(last_swap < 0, x0, v_gather) & p
-    s_last = jnp.take_along_axis(
-        s_excl, jnp.maximum(last_swap, 0)[:, None].astype(jnp.int32), axis=1
-    )[:, 0]
-    s_last = jnp.where(last_swap < 0, 0, s_last)
-    par_end = total - s_last
-    if reverse:
-        # reverse: the i==0 self-invert happens after everything
-        par_end = par_end + cond0.astype(jnp.int32)
-    v_end = jnp.where((par_end & 1) == 1, v_src ^ p, v_src)
-    x0_new = (x0 & np_) | v_end
-
-    if reverse:
-        body_new = body_new[:, ::-1]
-    return jnp.concatenate([x0_new[:, None], body_new], axis=1)
-
-
 def _prefix_xor(x: jax.Array) -> jax.Array:
-    """Inclusive prefix-XOR over axis 1 via Hillis-Steele doubling."""
-    n, d = x.shape
+    """Inclusive prefix-XOR along axis 0 via Hillis-Steele doubling."""
     s = 1
-    while s < d:
-        x = x ^ jnp.concatenate(
-            [jnp.zeros((n, s), x.dtype), x[:, :-s]], axis=1
-        )
+    while s < x.shape[0]:
+        x = x ^ jnp.concatenate([jnp.zeros_like(x[:s]), x[:-s]], axis=0)
         s <<= 1
     return x
+
+
+def _level_pass(x: jax.Array, level: int, reverse: bool) -> jax.Array:
+    """One level of Skilling's "inverse undo" on dims-major (d, n) coords.
+
+    Skilling's per-level loop threads a register (X[0]) through the dims:
+      i == 0:  if X[0] & Q: X[0] ^= P                     (invert register)
+      i >= 1:  if X[i] & Q: X[0] ^= P                     (invert register)
+               else:        t = (X[0]^X[i]) & P; X[0] ^= t; X[i] ^= t
+    Every step is an involution, so ``reverse=True`` (the inverse pass used
+    by :func:`transpose_to_axes`) runs the same steps backwards: dims
+    d-1..1, then the i == 0 step.
+
+    It runs as written, a ``lax.scan`` over dims carrying the register, each
+    step elementwise over the n points: one read and one write of each row
+    per level.  Not as prefix sums and a running max over dims: a TPU
+    lowers ``cumsum``/``cummax`` as a full-width ``reduce_window``, which
+    at d=384 moves ~720 GB per 65536 points (compiler cost analysis for a
+    TPU v5e).
+    """
+    q = jnp.uint32(1 << level)
+    p = jnp.uint32((1 << level) - 1)
+
+    def first(x0):
+        return jnp.where((x0 & q) != 0, x0 ^ p, x0)
+
+    def step(x0, xi):
+        invert = (xi & q) != 0
+        t = (x0 ^ xi) & p
+        return (jnp.where(invert, x0 ^ p, x0 ^ t),
+                jnp.where(invert, xi, xi ^ t))
+
+    x0 = x[0] if reverse else first(x[0])
+    x0, body = lax.scan(step, x0, x[1:], reverse=reverse)
+    if reverse:
+        x0 = first(x0)
+    return jnp.concatenate([x0[None], body], axis=0)
+
+
+def _axes_to_transpose_t(x: jax.Array, bits: int) -> jax.Array:
+    """:func:`axes_to_transpose` on dims-major (d, n) uint32 coordinates."""
+    # --- Inverse undo: for Q = M .. 2. ---
+    for level in range(bits - 1, 0, -1):
+        x = _level_pass(x, level, reverse=False)
+
+    # --- Gray encode: X[i] ^= X[i-1] (already-updated) == prefix-XOR. ---
+    # Hillis-Steele doubling instead of ``lax.associative_scan``: when the
+    # associative scan is fused with ``_level_pass`` under jit, XLA:CPU
+    # miscompiles the composition (observed at d=2, bits=2, jax 0.4.37:
+    # jitted keys disagree with op-by-op eval and collide).  Same O(log d)
+    # depth, no scan primitive for the fuser to mangle.
+    x = _prefix_xor(x)
+    t = jnp.zeros(x.shape[1:], jnp.uint32)
+    last = x[-1]
+    for level in range(bits - 1, 0, -1):
+        q = jnp.uint32(1 << level)
+        t = jnp.where((last & q) != 0, t ^ jnp.uint32((1 << level) - 1), t)
+    return x ^ t[None]
 
 
 def axes_to_transpose(coords: jax.Array, bits: int) -> jax.Array:
@@ -153,51 +128,30 @@ def axes_to_transpose(coords: jax.Array, bits: int) -> jax.Array:
       (n, d) uint32 "transpose" representation: bit ``l`` of output column
       ``i`` is Hilbert-index bit at stream position ``(bits-1-l)*d + i``.
     """
-    x = coords.astype(jnp.uint32)
-    n, d = x.shape
-
-    # --- Inverse undo: for Q = M .. 2 (scan-free level pass). ---
-    for level in range(bits - 1, 0, -1):
-        x = _level_pass(x, level, reverse=False)
-
-    # --- Gray encode: X[i] ^= X[i-1] (already-updated) == prefix-XOR. ---
-    # Hillis-Steele doubling instead of ``lax.associative_scan``: when the
-    # associative scan is fused with ``_level_pass`` under jit, XLA:CPU
-    # miscompiles the composition (observed at d=2, bits=2, jax 0.4.37:
-    # jitted keys disagree with op-by-op eval and collide).  Same O(log d)
-    # depth, no scan primitive for the fuser to mangle.
-    x = _prefix_xor(x)
-    t = jnp.zeros((n,), jnp.uint32)
-    last = x[:, -1]
-    for level in range(bits - 1, 0, -1):
-        q = jnp.uint32(1 << level)
-        t = jnp.where((last & q) != 0, t ^ jnp.uint32((1 << level) - 1), t)
-    return x ^ t[:, None]
+    return _axes_to_transpose_t(coords.astype(jnp.uint32).T, bits).T
 
 
 def transpose_to_axes(transpose: jax.Array, bits: int) -> jax.Array:
     """Inverse of :func:`axes_to_transpose` (used by tests/oracles)."""
-    x = transpose.astype(jnp.uint32)
-    n, d = x.shape
+    x = transpose.astype(jnp.uint32).T   # dims-major (d, n)
 
-    # Gray decode.  Forward computed t from the pre-XOR y[:, -1]; here we
+    # Gray decode.  Forward computed t from the pre-XOR y[-1]; here we
     # only have z = y ^ t, but t's contribution to bit `level` comes solely
     # from already-reconstructed higher levels, so probe (z ^ t_sofar).
-    t = jnp.zeros((n,), jnp.uint32)
-    last = x[:, -1]
+    t = jnp.zeros(x.shape[1:], jnp.uint32)
+    last = x[-1]
     for level in range(bits - 1, 0, -1):
         q = jnp.uint32(1 << level)
         t = jnp.where(((last ^ t) & q) != 0, t ^ jnp.uint32((1 << level) - 1), t)
-    x = x ^ t[:, None]
-    # Invert the prefix-XOR: X[i] ^= X[i+1]... walk from high index down.
-    # prefix-xor y[i] = x[0]^..^x[i]  =>  x[i] = y[i] ^ y[i-1].
-    x = jnp.concatenate([x[:, :1], x[:, 1:] ^ x[:, :-1]], axis=1)
+    x = x ^ t[None]
+    # Invert the prefix-XOR: y[i] = x[0]^..^x[i]  =>  x[i] = y[i] ^ y[i-1].
+    x = jnp.concatenate([x[:1], x[1:] ^ x[:-1]], axis=0)
 
     # Undo "inverse undo": same involutive level pass, run backwards
     # (dims d-1..1 then the i==0 op), levels in the opposite order.
     for level in range(1, bits):
         x = _level_pass(x, level, reverse=True)
-    return x
+    return x.T
 
 
 # ---------------------------------------------------------------------------
@@ -219,18 +173,22 @@ def quantize_points(
     return g.astype(jnp.uint32)
 
 
-def _pack_bits_to_words(bit_cols, n: int, key_bits: int) -> jax.Array:
-    """Pack a (n, L*d) {0,1} bit matrix into (n, W) uint32, MSB-first."""
+def _pack_bits_to_words(bit_rows: jax.Array, key_bits: int) -> jax.Array:
+    """Pack a dims-major (L*d, n) {0,1} bit matrix into (n, W) uint32,
+    MSB-first."""
     w = key_words(key_bits)
-    total = w * 32
-    bits_mat = bit_cols[:, :key_bits]
-    pad = total - bits_mat.shape[1]
+    bits_mat = bit_rows[:key_bits]
+    pad = w * 32 - bits_mat.shape[0]
     if pad:
-        bits_mat = jnp.pad(bits_mat, ((0, 0), (0, pad)))
-    bits_mat = bits_mat.reshape(n, w, 32).astype(jnp.uint32)
+        bits_mat = jnp.pad(bits_mat, ((0, pad), (0, 0)))
+    bits_mat = bits_mat.reshape(w, 32, -1).astype(jnp.uint32)
     shifts = (31 - jnp.arange(32, dtype=jnp.uint32)).astype(jnp.uint32)
-    words = jnp.sum(bits_mat << shifts[None, None, :], axis=-1, dtype=jnp.uint32)
-    return words
+    words = jnp.sum(bits_mat << shifts[None, :, None], axis=1,
+                    dtype=jnp.uint32)
+    return words.T
+
+
+KEY_CHUNK = 1 << 18
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "key_bits"))
@@ -256,35 +214,62 @@ def hilbert_keys(
 
     Returns:
       (n, W) uint32 packed keys, word 0 most significant.
+
+    Keys are computed ``KEY_CHUNK`` rows at a time (``lax.map``), which
+    bounds the dims-major temporaries (the (2d, rows) bit planes alone are
+    3 GB for 2^20 rows at d=384).  Rows are independent, so the keys do not
+    depend on the chunking.
     """
     n, d = points.shape
     if key_bits > d * bits:
         raise ValueError(f"key_bits={key_bits} exceeds d*bits={d * bits}")
-    coords = quantize_points(points, bits, lo, hi)
+    chunk = max(1, min(n, KEY_CHUNK))
+    n_chunks = -(-n // chunk)
+    padded = jnp.pad(points, ((0, n_chunks * chunk - n), (0, 0)))
+    keys = lax.map(
+        lambda rows: _keys_rows(rows, bits, key_bits, lo, hi, perm, flip),
+        padded.reshape(n_chunks, chunk, d),
+    )
+    return keys.reshape(-1, keys.shape[-1])[:n]
+
+
+def _keys_rows(points, bits, key_bits, lo, hi, perm, flip):
+    # Dims-major from here on: the axis permutation is a gather of whole
+    # rows, and the transform is elementwise over points.
+    d = points.shape[1]
+    coords = quantize_points(points, bits, lo, hi).T        # (d, n)
     if flip is not None:
         levels = jnp.uint32((1 << bits) - 1)
-        coords = jnp.where(flip[None, :], levels - coords, coords)
+        coords = jnp.where(flip[:, None], levels - coords, coords)
     if perm is not None:
-        coords = coords[:, perm]
-    tr = axes_to_transpose(coords, bits)
+        coords = coords[perm]
+    tr = _axes_to_transpose_t(coords, bits)
     # Interleave MSB-level-first: level b-1 of all dims, then b-2, ...
     n_levels = -(-key_bits // d)
-    cols = []
-    for j in range(n_levels):
-        level = bits - 1 - j
-        cols.append((tr >> jnp.uint32(level)) & jnp.uint32(1))
-    bit_cols = jnp.concatenate(cols, axis=1)
-    return _pack_bits_to_words(bit_cols, n, key_bits)
+    rows = [(tr >> jnp.uint32(bits - 1 - j)) & jnp.uint32(1)
+            for j in range(n_levels)]
+    return _pack_bits_to_words(jnp.concatenate(rows, axis=0), key_bits)
 
 
-def _lexsort_words(keys: jax.Array) -> jax.Array:
-    """argsort of (n, W) packed keys, lexicographic, word 0 primary."""
-    w = keys.shape[1]
-    # jnp.lexsort: LAST key is the primary sort key.
-    return jnp.lexsort(tuple(keys[:, i] for i in range(w - 1, -1, -1)))
+@jax.jit
+def lexsort_words(keys: jax.Array) -> jax.Array:
+    """argsort of (n, W) packed keys, lexicographic, word 0 primary.
+
+    Least-significant word first, W stable single-key sorts in a
+    ``fori_loop``: equal keys keep their index order.  One ``lax.sort``
+    over all W words gives the same order, but the TPU compiler takes
+    minutes for it at n ≈ 2^20 (its compile time grows with the operand
+    count and with n); the loop body's two-operand sort compiles once.
+    """
+    n, w = keys.shape
+
+    def body(i, order):
+        word = keys[order, w - 1 - i]
+        return lax.sort((word, order), num_keys=1, is_stable=True)[1]
+
+    return lax.fori_loop(0, w, body, jnp.arange(n, dtype=jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "key_bits"))
 def hilbert_sort(
     points: jax.Array,
     *,
@@ -300,11 +285,16 @@ def hilbert_sort(
     ``order`` is an int32 permutation such that ``points[order]`` walks the
     (truncated) Hilbert curve; ``sorted_keys`` are the packed keys in that
     order (used to build the rank directory / "compressed Hilbert tree").
+
+    Not jitted as a whole: called outside ``jit``, the key pass and the
+    sort are separate dispatches, so every Hilbert sort of the same row
+    count — the forest's trees, the master order, the k-NN graph's orders
+    — shares one compiled sort.
     """
     keys = hilbert_keys(
         points, bits=bits, key_bits=key_bits, lo=lo, hi=hi, perm=perm, flip=flip
     )
-    order = _lexsort_words(keys).astype(jnp.int32)
+    order = lexsort_words(keys)
     return order, keys[order]
 
 

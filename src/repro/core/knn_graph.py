@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core import hilbert, quantize, sketch
+from repro.core import hilbert, quantize, search, sketch
 from repro.core.types import ForestConfig, GraphParams, QuantizerConfig
 
 __all__ = ["build_knn_graph", "knn_graph_from_sketches"]
@@ -42,44 +42,67 @@ __all__ = ["build_knn_graph", "knn_graph_from_sketches"]
 _INF = jnp.int32(2**30)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "key_bits"))
 def order_and_rank(points, lo, hi, perm, flip, *, bits, key_bits):
-    """One Hilbert order + its inverse rank (pure stage)."""
+    """One Hilbert order + its inverse rank (pure stage; not jitted, so it
+    shares the compiled sort of the index build)."""
     order, _ = hilbert.hilbert_sort(
         points, bits=bits, key_bits=key_bits, lo=lo, hi=hi, perm=perm, flip=flip
     )
+    return order, _inverse_permutation(order)
+
+
+@jax.jit
+def _inverse_permutation(order):
     n = order.shape[0]
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
-    return order, rank
+    return jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+
+
+# Rows per pass of merge_order: its (rows, k1, W) candidate-sketch gather
+# and (rows, k2 + k1) sort need ~14 GB at 2^20 rows in one pass.
+MERGE_CHUNK = 1 << 16
 
 
 @functools.partial(jax.jit, static_argnames=("k1", "k2"))
 def merge_order(best_id, best_dist, order, rank, sketches, *, k1, k2):
-    """Merge one Hilbert order's rank-window candidates into the top-k2."""
+    """Merge one Hilbert order's rank-window candidates into the top-k2.
+
+    Rows are merged in blocks of at most ``MERGE_CHUNK`` (``lax.map``);
+    rows are independent, so the result does not depend on the blocking.
+    """
+    n = order.shape[0]
+    chunk = max(1, min(n, MERGE_CHUNK))
+    n_chunks = -(-n // chunk)
+    pad = n_chunks * chunk - n
+    rows = jnp.minimum(jnp.arange(n + pad, dtype=jnp.int32), n - 1)
+
+    def block(x, fill):
+        x = jnp.pad(x, ((0, pad), (0, 0)), constant_values=fill)
+        return x.reshape(n_chunks, chunk, x.shape[-1])
+
+    ids, dist = lax.map(
+        lambda a: _merge_rows(*a, order, rank, sketches, k1=k1, k2=k2),
+        (rows.reshape(n_chunks, chunk), block(best_id, -1),
+         block(best_dist, _INF)),
+    )
+    return ids.reshape(-1, k2)[:n], dist.reshape(-1, k2)[:n]
+
+
+def _merge_rows(rows, best_id, best_dist, order, rank, sketches, *, k1, k2):
     n = order.shape[0]
     half = k1 // 2
     # ±half window around each point's rank, self excluded by distance mask.
     deltas = jnp.concatenate(
         [jnp.arange(-half, 0, dtype=jnp.int32), jnp.arange(1, k1 - half + 1, dtype=jnp.int32)]
     )  # k1 offsets, 0 excluded
-    pos = rank[:, None] + deltas[None, :]
+    pos = rank[rows][:, None] + deltas[None, :]
     pos = jnp.clip(pos, 0, n - 1)
-    cand = order[pos]  # (N, k1) ids
-    hd = sketch.hamming_distance(sketches[:, None, :], sketches[cand])
-    self_mask = cand == jnp.arange(n, dtype=jnp.int32)[:, None]
+    cand = order[pos]  # (rows, k1) ids
+    hd = sketch.hamming_distance(sketches[rows][:, None, :], sketches[cand])
+    self_mask = cand == rows[:, None]
     hd = jnp.where(self_mask, _INF, hd)
 
-    ids = jnp.concatenate([best_id, cand], axis=1)
-    dist = jnp.concatenate([best_dist, hd], axis=1)
-    sort_idx = jnp.argsort(ids, axis=1)
-    ids_s = jnp.take_along_axis(ids, sort_idx, axis=1)
-    dist_s = jnp.take_along_axis(dist, sort_idx, axis=1)
-    dup = jnp.concatenate(
-        [jnp.zeros_like(ids_s[:, :1], bool), ids_s[:, 1:] == ids_s[:, :-1]], axis=1
-    )
-    dist_s = jnp.where(dup, _INF, dist_s)
-    neg, idx = lax.top_k(-dist_s, k2)
-    return jnp.take_along_axis(ids_s, idx, axis=1), -neg
+    return search._merge_topk_dedup(best_id, best_dist, cand, hd, k2)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -104,7 +127,7 @@ def knn_graph_from_sketches(
     key_bits: int,
     lo: jax.Array,
     hi: jax.Array,
-    chunk: int = 1 << 16,
+    chunk: int = 1 << 14,
 ) -> Tuple[jax.Array, jax.Array]:
     """Full Algorithm-2 pipeline over pre-computed sketches (pure function).
 
@@ -126,7 +149,7 @@ def knn_graph_from_sketches(
             best_id, best_dist, order, rank, sketches, k1=params.k1, k2=params.k2
         )
     # Final exact selection, chunked over points to bound the (N, k2, d)
-    # gather transient.
+    # gather transient (~3 GB per 2^14 rows at k2=60, d=384).
     ids_out, d_out = [], []
     for s in range(0, n, chunk):
         ids_c, d_c = final_select_chunk(
@@ -142,7 +165,7 @@ def build_knn_graph(
     params: GraphParams,
     quant_cfg: QuantizerConfig = QuantizerConfig(),
     forest_cfg: ForestConfig = ForestConfig(),
-    chunk: int = 1 << 16,
+    chunk: int = 1 << 14,
 ) -> Tuple[jax.Array, jax.Array]:
     """DEPRECATED: use ``repro.index.HilbertIndex.build(...).knn_graph(...)``.
 

@@ -48,11 +48,16 @@ class Quantizer(NamedTuple):
 
 
 def fit(data: jax.Array, bits: int = 4, sample_limit: int = 262144) -> Quantizer:
-    """Fit per-dimension quantile boundaries/centroids on (a sample of) data."""
+    """Fit per-dimension quantile boundaries/centroids on (a sample of) data.
+
+    ``data`` may be a host (numpy) array: only the sample then goes to the
+    device, which is how the sharded build avoids staging a whole corpus
+    on one device.
+    """
     n = data.shape[0]
     if n > sample_limit:
         idx = np.random.default_rng(0).choice(n, sample_limit, replace=False)
-        data = data[jnp.asarray(idx)]
+        data = data[idx]
     levels = 1 << bits
     qs_b = jnp.arange(1, levels) / levels
     qs_c = (jnp.arange(levels) + 0.5) / levels

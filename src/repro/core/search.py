@@ -121,9 +121,11 @@ class HilbertForestIndex(NamedTuple):
         )
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
 def hilbert_master_sort(points, cfg: ForestConfig, lo, hi):
-    """Un-permuted Hilbert sort defining the master order (pure stage)."""
+    """Un-permuted Hilbert sort defining the master order (pure stage).
+
+    Not jitted, so it shares the compiled sort of the forest's trees
+    (:func:`repro.core.hilbert.hilbert_sort`)."""
     from repro.core import hilbert
 
     return hilbert.hilbert_sort(
@@ -132,20 +134,26 @@ def hilbert_master_sort(points, cfg: ForestConfig, lo, hi):
 
 
 def _merge_topk_dedup(best_pos, best_dist, new_pos, new_dist, k: int):
-    """Merge candidate sets keyed by position; dedup; keep k smallest dists."""
+    """Merge candidate sets keyed by position; dedup; keep k smallest dists.
+
+    Also the k-NN graph's per-order merge (keyed by point id).  Two stable
+    sorts that carry their payload, so no gathers: a TPU runs
+    ``take_along_axis`` as an element gather.  Same result as argsort +
+    gathers + ``lax.top_k`` (whose ties also go to the lower index).
+    """
     pos = jnp.concatenate([best_pos, new_pos], axis=1)
     dist = jnp.concatenate([best_dist, new_dist], axis=1)
     # Dedup: sort by position; equal-adjacent entries are duplicates (same
     # position ⇒ same sketch ⇒ same distance), mask all but the first.
-    sort_idx = jnp.argsort(pos, axis=1)
-    pos_s = jnp.take_along_axis(pos, sort_idx, axis=1)
-    dist_s = jnp.take_along_axis(dist, sort_idx, axis=1)
+    pos_s, dist_s = lax.sort((pos, dist), dimension=1, num_keys=1,
+                             is_stable=True)
     dup = jnp.concatenate(
         [jnp.zeros_like(pos_s[:, :1], bool), pos_s[:, 1:] == pos_s[:, :-1]], axis=1
     )
     dist_s = jnp.where(dup, _INF, dist_s)
-    neg, idx = lax.top_k(-dist_s, k)
-    return jnp.take_along_axis(pos_s, idx, axis=1), -neg
+    dist_o, pos_o = lax.sort((dist_s, pos_s), dimension=1, num_keys=1,
+                             is_stable=True)
+    return pos_o[:, :k], dist_o[:, :k]
 
 
 @functools.partial(
@@ -281,8 +289,8 @@ def stage2_packed_windows(
     Candidate codes are read as contiguous ±h windowed dynamic slices of
     the packed words (0.5 B/dim of traffic).  Distances route through
     ``repro.kernels.qdist.qdist_windows_from_packed``: the Pallas kernel
-    when ``use_kernels`` (TPU target; interpret mode on CPU), else a packed
-    XLA path that unpacks losslessly — bit-identical to
+    when ``use_kernels`` (Mosaic on TPU, see ``repro.kernels.platform``),
+    else a packed XLA path that unpacks losslessly — bit-identical to
     :func:`stage2_expand_rank` on the same candidates.
     """
     n = master_order.shape[0]
@@ -296,7 +304,6 @@ def stage2_packed_windows(
 
         d2 = qdist_windows_from_packed(
             queries, win, quant.centroids, d=d, use_kernel=True,
-            interpret=jax.default_backend() != "tpu",
         )
     else:
         d2 = quantize.adc_distance_packed(quant, queries, win, d=d)
@@ -490,10 +497,15 @@ def brute_force_topk(queries, points, valid, *, k):
     ||q-p||^2 = ||q||^2 - 2<q,p> + ||p||^2 so the transient is (Q, B), not
     (Q, B, d).  Returns (row indices into ``points`` (Q, k), d2 (Q, k));
     masked rows surface as d2 = +inf.
+
+    The cross term runs at ``Precision.HIGHEST``: a TPU's default f32 matmul
+    is a single bf16 pass, which would make these "exact" answers (and the
+    post-compact bit-equality they feed) depend on the device.
     """
     qq = jnp.sum(queries * queries, axis=1)[:, None]
     pp = jnp.sum(points * points, axis=1)[None, :]
-    d2 = jnp.maximum(qq - 2.0 * (queries @ points.T) + pp, 0.0)
+    cross = jnp.matmul(queries, points.T, precision=lax.Precision.HIGHEST)
+    d2 = jnp.maximum(qq - 2.0 * cross + pp, 0.0)
     d2 = jnp.where(valid[None, :], d2, jnp.inf)
     neg, idx = lax.top_k(-d2, k)
     return idx, -neg

@@ -79,9 +79,14 @@ def lowrank_embeddings(
     u /= np.linalg.norm(u, axis=1, keepdims=True)
     spec = ((1.0 + np.arange(r)) ** -0.5).astype(np.float32)
     z = rng.normal(size=(n, r)).astype(np.float32) * spec
-    x = centers[assign] + noise * np.einsum("ndr,nr->nd", u[assign], z)
+    # One (rows, r) @ (r, d) product per cluster: a per-row gather of the
+    # (d, r) bases would move n*d*r floats (26 GB at n=2^20).
+    x = np.empty((n, d), np.float32)
+    for c in range(n_clusters):
+        rows = np.flatnonzero(assign == c)
+        x[rows] = centers[c] + noise * (z[rows] @ u[c].T)
     x /= np.linalg.norm(x, axis=1, keepdims=True)
-    return x.astype(np.float32)
+    return x
 
 
 def lowrank_dataset_with_queries(

@@ -38,7 +38,11 @@ class IndexConfig:
         at most ``log2(query_chunk)+1`` traces across all batch sizes.
         Travels with the index so every serving worker shares the same
         trace-bucket policy; overridable per call via
-        ``search(query_chunk=...)``.
+        ``search(query_chunk=...)``.  The default 512 bounds a chunk's
+        scratch at Table-1 row 1 (d=384, k2=370, h=2): the XLA route
+        dequantizes every candidate window, ~5.6 GiB at 512 queries and
+        4x that at 2048 — more than a 16 GB TPU v5e holds beside the
+        index (``tests/test_tpu_compile.py`` checks the bound).
       shards: row-partition count for the sharded facade.  ``None`` (the
         default) means "auto": :func:`repro.index.build_auto` picks one
         shard per device on the mesh's ``data`` axis when more than one
@@ -82,7 +86,7 @@ class IndexConfig:
     forest: ForestConfig = ForestConfig()
     quantizer: QuantizerConfig = QuantizerConfig()
     store_points: bool = True
-    query_chunk: int = 2048
+    query_chunk: int = 512
     shards: Optional[int] = None
     mutable: bool = False
     seal_pow2: bool = False
@@ -123,7 +127,7 @@ class IndexConfig:
                 **_filter_fields(QuantizerConfig, d.get("quantizer", {}))
             ),
             store_points=bool(d.get("store_points", True)),
-            query_chunk=int(d.get("query_chunk", 2048)),
+            query_chunk=int(d.get("query_chunk", 512)),
             shards=None if shards is None else int(shards),
             mutable=bool(d.get("mutable", False)),
             seal_pow2=bool(d.get("seal_pow2", False)),

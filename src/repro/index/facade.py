@@ -388,7 +388,7 @@ class HilbertIndex:
         self,
         params: GraphParams = GraphParams(),
         *,
-        chunk: int = 1 << 16,
+        chunk: int = 1 << 14,
     ) -> Tuple[jax.Array, jax.Array]:
         """Approximate k-NN graph over the indexed points — the paper's
         Algorithm 2 (Task 2): repeated randomized Hilbert orders, ±k1

@@ -15,8 +15,8 @@ module layers classic LSM machinery over the immutable facade:
   stable external id.
 * **Tombstones** — deletes only flip a bit in a dense ``alive`` mask; search
   masks dead candidates during the cross-segment merge, and each segment's
-  per-query ``k`` is inflated by its dead count so tombstones cannot eat
-  result slots.
+  per-query ``k`` is inflated by its dead count (rounded up to a power of
+  two) so tombstones cannot eat result slots.
 * **Tiered compaction** — when segments pile up, the smallest two are merged
   by concatenating their stored points, dropping tombstoned rows for good,
   re-sorting (one cheap Hilbert-forest build), and remapping ids.
@@ -97,8 +97,8 @@ _MAX_IDS = 2**31 - 1  # external ids are int32
 
 
 def _pow2_ceil(x: int) -> int:
-    """Smallest power of two >= x (>= 1)."""
-    return 1 << max(0, int(x) - 1).bit_length()
+    """0 for x<=0, else the smallest power of two >= x."""
+    return 0 if x <= 0 else 1 << (int(x) - 1).bit_length()
 
 
 class LsmIdSpace:
@@ -823,8 +823,9 @@ class MutableHilbertIndex(WalFacade):
         ADC (asymmetric vs 4-bit codes) as in the paper; buffer distances
         are exact fp32 — both approximate the true metric, and the merge
         compares them directly.  Each segment is queried for
-        ``k + (its tombstone count)`` so masked rows cannot displace live
-        results — up to the stage-2 candidate pool (``k2*(2h+1)``).  A
+        ``k + (its tombstone count, rounded up to a power of two)`` so
+        masked rows cannot displace live results — up to the stage-2
+        candidate pool (``k2*(2h+1)``).  A
         segment tombstoned past that bound is rewritten on the spot
         (read-triggered compaction) when it stores raw points; without
         stored points (or with ``allow_rewrite=False``) its recall
@@ -834,6 +835,9 @@ class MutableHilbertIndex(WalFacade):
             params = SearchParams()
         q = jnp.asarray(queries)
         qn, k = q.shape[0], params.k
+        # The buffer search and the merge run on qp rows, a power of two,
+        # so batch sizes compile log-many times (segments bucket their own).
+        qp = _pow2_ceil(qn)
         # stage-2 candidate pool per segment; lax.top_k caps k there.
         cap = params.k2 * (2 * params.h + 1)
         parts_ids: List[np.ndarray] = []
@@ -856,7 +860,11 @@ class MutableHilbertIndex(WalFacade):
                 if seg is None:  # segment was fully tombstoned
                     continue
                 dead = 0
-                need = k * (2 if seg.n_pad else 1)
+            # The segment is asked for k plus a pow2 bucket of its dead
+            # count (as on the sharded layout): k is a static argument of
+            # the compiled search, so deletes then recompile it log-many
+            # times instead of once per distinct tombstone count.
+            need = (k + _pow2_ceil(dead)) * (2 if seg.n_pad else 1)
             k_seg = search_lib.inflate_k(k, need - k, cap)
             sids, sd2 = seg.index.search(
                 q, dataclasses.replace(params, k=k_seg),
@@ -871,11 +879,12 @@ class MutableHilbertIndex(WalFacade):
             valid[: self._buf_count] = self._alive[bids]
             with dispatch_scope("lsm.buffer_search"):
                 idx, bd2 = search_lib.brute_force_topk(
-                    q, jnp.asarray(self._buf_points), jnp.asarray(valid),
+                    jnp.pad(q, ((0, qp - qn), (0, 0))),
+                    jnp.asarray(self._buf_points), jnp.asarray(valid),
                     k=min(k, self.buffer_capacity),
                 )
-            parts_ids.append(self._buf_ids[np.asarray(idx)])
-            parts_d.append(np.asarray(bd2, np.float32))
+            parts_ids.append(self._buf_ids[np.asarray(idx)[:qn]])
+            parts_d.append(np.asarray(bd2, np.float32)[:qn])
         if not parts_ids:
             return (
                 jnp.full((qn, k), -1, jnp.int32),
@@ -888,10 +897,15 @@ class MutableHilbertIndex(WalFacade):
         # same `merge_topk` the sharded index uses across shards.
         dead = ~self._alive[np.clip(ids, 0, max(self._next_id - 1, 0))]
         d2 = np.where(dead, np.inf, d2)
+        pad = ((0, qp - qn), (0, 0))
         with dispatch_scope("lsm.merge"):
-            return search_lib.merge_topk(
-                jnp.asarray(ids, jnp.int32), jnp.asarray(d2, jnp.float32), k=k
+            ids, d2 = search_lib.merge_topk(
+                jnp.asarray(np.pad(ids, pad, constant_values=-1), jnp.int32),
+                jnp.asarray(np.pad(d2, pad, constant_values=np.inf),
+                            jnp.float32),
+                k=k,
             )
+        return ids[:qn], d2[:qn]
 
     # -- values --------------------------------------------------------------
 

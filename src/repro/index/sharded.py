@@ -60,13 +60,14 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import checkpoint
 from repro.core import distributed as distributed_lib
@@ -144,11 +145,17 @@ def stack_shard_indexes(
     ``id_map`` is simply what local search hits are gathered through.
     """
     data_sh = NamedSharding(mesh, P("data"))
+    devices = list(mesh.devices.flat)
 
     def stack_leaf(get):
-        return jax.device_put(
-            jnp.stack([get(ix) for ix in shard_indexes]), data_sh
-        )
+        # Assemble from per-device pieces: shard s moves (if at all) only to
+        # device s, so no device ever stages the whole stack.
+        parts = [
+            jax.device_put(get(ix)[None], dev)
+            for ix, dev in zip(shard_indexes, devices)
+        ]
+        shape = (len(parts),) + parts[0].shape[1:]
+        return jax.make_array_from_single_device_arrays(shape, data_sh, parts)
 
     stack = ShardStack(
         orders=stack_leaf(lambda ix: ix.forest.orders),
@@ -159,7 +166,7 @@ def stack_shard_indexes(
         codes=stack_leaf(lambda ix: ix.codes_master),
         master_order=stack_leaf(lambda ix: ix.master_order),
         master_rank=stack_leaf(lambda ix: ix.master_rank),
-        id_map=jax.device_put(jnp.asarray(id_maps, jnp.int32), data_sh),
+        id_map=jax.device_put(np.asarray(id_maps, np.int32), data_sh),
     )
     points = stack_leaf(lambda ix: ix.points) if store_points else None
     return stack, points
@@ -335,7 +342,7 @@ class ShardedHilbertIndex:
                 f"{n_shards}; pass a matching mesh (launch.mesh.data_mesh)"
             )
         quant = quantize.fit(
-            jnp.asarray(pts), bits=config.quantizer.bits,
+            pts, bits=config.quantizer.bits,
             sample_limit=config.quantizer.sample_limit,
         )
         return cls._build_impl(pts, config, mesh, quant)
@@ -362,7 +369,7 @@ class ShardedHilbertIndex:
             )
 
         gid_slices = distributed_lib.hilbert_partition(
-            jnp.asarray(pts), config.forest, mesh=mesh, n_shards=n_shards
+            pts, config.forest, mesh=mesh, n_shards=n_shards
         )
         n_pad = -(-n // n_shards)
         n_valid = np.asarray([len(g) for g in gid_slices], np.int64)
@@ -372,7 +379,6 @@ class ShardedHilbertIndex:
         pad_max = int(max(
             (n_pad - v for v in n_valid if v > 0), default=0
         ))
-        shard_indexes: List[HilbertIndex] = []
         id_maps = np.zeros((n_shards, n_pad), np.int32)
         for s, gids in enumerate(gid_slices):
             if len(gids) == 0:
@@ -381,10 +387,21 @@ class ShardedHilbertIndex:
                 reps = -(-n_pad // len(gids))
                 gids_pad = np.tile(np.asarray(gids, np.int32), reps)[:n_pad]
             id_maps[s] = gids_pad
-            idx, _ = build_with_timings(
-                jnp.asarray(pts[gids_pad]), config, quant=quant
-            )
-            shard_indexes.append(idx)
+
+        def build_shard(s: int) -> HilbertIndex:
+            # Each shard is built on the device that will hold it, all at
+            # once: a jitted program compiles per device, so one thread per
+            # device overlaps the compiles as well as the builds.
+            dev = mesh.devices.flat[s]
+            with jax.default_device(dev):
+                idx, _ = build_with_timings(
+                    jax.device_put(pts[id_maps[s]], dev), config,
+                    quant=jax.device_put(quant, dev),
+                )
+            return idx
+
+        with ThreadPoolExecutor(max_workers=n_shards) as pool:
+            shard_indexes = list(pool.map(build_shard, range(n_shards)))
         return cls._assemble(
             config, mesh, quant, shard_indexes, id_maps, n, n_valid, pad_max
         )
@@ -592,7 +609,7 @@ class ShardedHilbertIndex:
                 mesh=mesh,
                 in_specs=(P(None, None), P("data"), P(), P(), P()),
                 out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
         )
         self._chunk_fns.put(key, fn)

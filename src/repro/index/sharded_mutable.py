@@ -62,8 +62,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import checkpoint
 from repro.core import distributed as distributed_lib
@@ -1050,7 +1050,7 @@ class ShardedMutableHilbertIndex(WalFacade):
                 in_specs=(P(None, None), P("data"), P(), P(), P(),
                           P("data"), P("data"), P()),
                 out_specs=(P(None, None), P(None, None)),
-                check_rep=False,
+                check_vma=False,
             )
         )
         self._chunk_fns.put(key, fn)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 BN = 256
 BW = 4  # words per tile -> 128 bit-columns, one lane register
 
@@ -30,22 +32,20 @@ def _pack_kernel(bits_ref, out_ref):
     out_ref[...] = jnp.sum(b3 << shifts, axis=-1, dtype=jnp.uint32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "bn", "bw"))
+@functools.partial(jax.jit, static_argnames=("bn", "bw"))
 def pack_bits_kernel(
     bits: jax.Array,          # (N, K) uint8/bool in {0,1}; K % (32*bw) == 0
     *,
-    interpret: bool = False,
     bn: int = BN,
     bw: int = BW,
 ) -> jax.Array:
     n, k = bits.shape
     w = k // 32
     grid = (n // bn, w // bw)
-    return pl.pallas_call(
+    return pallas_call(
         _pack_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bn, bw * 32), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bn, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, w), jnp.uint32),
-        interpret=interpret,
     )(bits.astype(jnp.uint8))

@@ -11,12 +11,11 @@ from repro.kernels.bitpack.kernel import BN, BW, pack_bits_kernel
 from repro.kernels.bitpack.ref import pack_bits_ref
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def pack_bits(
     bits: jax.Array,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """Pack a (N, K) {0,1} matrix into (N, ceil(K/32)) uint32 (MSB-first)."""
     n, k = bits.shape
@@ -26,5 +25,5 @@ def pack_bits(
         padded = jnp.pad(bits, ((0, 0), (0, kp - k)))
         return pack_bits_ref(padded)[:, : -(-k // 32)]
     padded = jnp.pad(bits, ((0, np_ - n), (0, kp - k)))
-    out = pack_bits_kernel(padded, interpret=interpret)
+    out = pack_bits_kernel(padded)
     return out[:n, : -(-k // 32)]

@@ -10,7 +10,13 @@ Tiling: grid (Q/BQ, C/BC); per step the kernel holds a (BQ, W) query tile
 and a (BC, W) candidate tile in VMEM and emits a (BQ, BC) int32 tile.  The
 XOR+popcount runs on the VPU; popcount is SWAR bit-twiddling (portable to
 interpret mode and Mosaic alike).  W (words per sketch) stays un-tiled: it
-is ≤ 16 for every config we ship (512-bit sketches).
+is ≤ 16 for every config we ship (512-bit sketches).  Popcounts are summed
+in int32: Mosaic has no reduction over unsigned integers.
+
+The row-wise kernel (stage 1 of Algorithm 1) takes its candidates
+word-major, ``(Q, W, K)``: candidates on the 128 lanes, the W sketch words
+on sublanes.  Laid out ``(Q, K, W)`` a W=12 word row would pad to 128 lanes
+and a (BQ, 1420, 12) block would need ~93 MB of VMEM.
 """
 
 from __future__ import annotations
@@ -21,11 +27,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 # Default tile sizes: (8, 128) is the fp32/int32 minimum tile; 128×128
 # output tiles keep the VMEM working set at
 #   BQ·W + BC·W + BQ·BC words  ≈  128·16·2·4B + 64KB ≈ 320 KB  « 16 MB VMEM.
 BQ = 128
 BC = 128
+# Row-wise kernel tiles: 8 queries × up to 512 candidates per step, i.e. a
+# (8, W≤16, 512) uint32 candidate block of at most 256 KB.
+ROWS_BQ = 8
+ROWS_BK = 512
 
 
 def _popcount32(v: jax.Array) -> jax.Array:
@@ -40,15 +52,14 @@ def _hamming_kernel(q_ref, c_ref, out_ref):
     q = q_ref[...]  # (BQ, W) uint32
     c = c_ref[...]  # (BC, W) uint32
     x = jnp.bitwise_xor(q[:, None, :], c[None, :, :])  # (BQ, BC, W)
-    out_ref[...] = jnp.sum(_popcount32(x), axis=-1).astype(jnp.int32)
+    out_ref[...] = jnp.sum(_popcount32(x).astype(jnp.int32), axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "bq", "bc"))
+@functools.partial(jax.jit, static_argnames=("bq", "bc"))
 def hamming_matrix_kernel(
     queries: jax.Array,
     candidates: jax.Array,
     *,
-    interpret: bool = False,
     bq: int = BQ,
     bc: int = BC,
 ) -> jax.Array:
@@ -59,7 +70,7 @@ def hamming_matrix_kernel(
     qn, w = queries.shape
     cn, _ = candidates.shape
     grid = (qn // bq, cn // bc)
-    return pl.pallas_call(
+    return pallas_call(
         _hamming_kernel,
         grid=grid,
         in_specs=[
@@ -68,39 +79,38 @@ def hamming_matrix_kernel(
         ],
         out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, cn), jnp.int32),
-        interpret=interpret,
     )(queries, candidates)
 
 
 def _hamming_rows_kernel(q_ref, c_ref, out_ref):
-    q = q_ref[...]  # (BQ, W)
-    c = c_ref[...]  # (BQ, K, W)
-    x = jnp.bitwise_xor(q[:, None, :], c)
-    out_ref[...] = jnp.sum(_popcount32(x), axis=-1).astype(jnp.int32)
+    q = q_ref[...]  # (BQ, W, 1)
+    c = c_ref[...]  # (BQ, W, BK)
+    x = jnp.bitwise_xor(c, q)
+    out_ref[...] = jnp.sum(_popcount32(x).astype(jnp.int32), axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "bq"))
+@functools.partial(jax.jit, static_argnames=("bq", "bk"))
 def hamming_rows_kernel(
-    queries: jax.Array,      # (Q, W) uint32
-    candidates: jax.Array,   # (Q, K, W) uint32 — per-query gathered sets
+    queries: jax.Array,      # (Q, W, 1) uint32
+    candidates: jax.Array,   # (Q, W, K) uint32 — per-query sets, word-major
     *,
-    interpret: bool = False,
-    bq: int = BQ,
+    bq: int = ROWS_BQ,
+    bk: int = ROWS_BK,
 ) -> jax.Array:
     """Row-wise Hamming: each query scored against ITS OWN K candidates —
     the exact stage-1 access pattern of Algorithm 1 (forest windows are
-    per-query).  Q must be a multiple of bq (ops.py pads)."""
-    qn, w = queries.shape
-    k = candidates.shape[1]
-    grid = (qn // bq,)
-    return pl.pallas_call(
+    per-query).  Q must be a multiple of bq and K of bk (ops.py pads and
+    re-lays the candidates word-major)."""
+    qn, w, _ = queries.shape
+    k = candidates.shape[2]
+    grid = (qn // bq, k // bk)
+    return pallas_call(
         _hamming_rows_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq, w), lambda i: (i, 0)),
-            pl.BlockSpec((bq, k, w), lambda i: (i, 0, 0)),
+            pl.BlockSpec((bq, w, 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((bq, w, bk), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((bq, k), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bq, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, k), jnp.int32),
-        interpret=interpret,
     )(queries, candidates)

@@ -1,9 +1,8 @@
 """Jit'd public wrapper: pads to tile multiples, dispatches kernel/oracle.
 
-On this container (CPU) the Pallas kernel runs in interpret mode, which is
-Python-slow; the default path on CPU is therefore the jnp oracle, with
-``use_kernel=True`` (interpret) reserved for correctness tests.  On TPU the
-kernel path is the default.
+``use_kernel=True`` runs the Pallas kernel: compiled by Mosaic when lowered
+for a TPU, in Pallas's interpreter when lowered for the CPU (see
+``repro.kernels.platform``).  ``use_kernel=False`` is the jnp oracle.
 """
 
 from __future__ import annotations
@@ -12,8 +11,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from repro.kernels.hamming.kernel import (BC, BQ, hamming_matrix_kernel,
+from repro.kernels.hamming.kernel import (BC, BQ, ROWS_BK, ROWS_BQ,
+                                          hamming_matrix_kernel,
                                           hamming_rows_kernel)
 from repro.kernels.hamming.ref import hamming_matrix_ref
 
@@ -27,21 +28,19 @@ def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def hamming_matrix(
     queries: jax.Array,
     candidates: jax.Array,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """Batched Hamming distances between packed uint32 sketch matrices.
 
     Args:
       queries: (Q, W) uint32.
       candidates: (C, W) uint32.
-      use_kernel: route through the Pallas kernel (TPU target; interpret on
-        CPU) instead of the jnp oracle.
+      use_kernel: route through the Pallas kernel instead of the jnp oracle.
 
     Returns:
       (Q, C) int32.
@@ -51,27 +50,25 @@ def hamming_matrix(
     qn, cn = queries.shape[0], candidates.shape[0]
     qp = _pad_to(queries, BQ, 0)
     cp = _pad_to(candidates, BC, 0)
-    out = hamming_matrix_kernel(qp, cp, interpret=interpret)
+    out = hamming_matrix_kernel(qp, cp)
     return out[:qn, :cn]
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def hamming_rows(
     queries: jax.Array,
     candidates: jax.Array,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """(Q, W) vs per-query (Q, K, W) packed sketches -> (Q, K) int32."""
     if not use_kernel:
-        import jax.numpy as _jnp
-        from jax import lax as _lax
-
-        x = _jnp.bitwise_xor(queries[:, None, :], candidates)
-        return _jnp.sum(_lax.population_count(x).astype(_jnp.int32), axis=-1)
-    qn = queries.shape[0]
-    qp = _pad_to(queries, BQ, 0)
-    cp = _pad_to(candidates, BQ, 0)
-    out = hamming_rows_kernel(qp, cp, interpret=interpret)
-    return out[:qn]
+        x = jnp.bitwise_xor(queries[:, None, :], candidates)
+        return jnp.sum(lax.population_count(x).astype(jnp.int32), axis=-1)
+    qn, k = candidates.shape[:2]
+    bk = min(ROWS_BK, -(-k // 128) * 128)
+    # Word-major candidates (Q, W, K): K on lanes (see kernel.py).
+    qp = _pad_to(queries, ROWS_BQ, 0)[:, :, None]
+    cp = _pad_to(_pad_to(jnp.swapaxes(candidates, 1, 2), ROWS_BQ, 0), bk, 2)
+    out = hamming_rows_kernel(qp, cp, bk=bk)
+    return out[:qn, :k]

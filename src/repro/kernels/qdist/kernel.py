@@ -20,10 +20,14 @@ Three variants:
     is identical.  The cross term becomes 8 accumulated (BQ,W)@(W,BC)
     matmuls.
   * ``qdist_packed_windows_kernel`` — the stage-2 serving shape: every query
-    brings its OWN candidate set (Q, C, d//8) uint32 (the ±h master-order
-    windows gathered by the fused search path), so the grid walks one query
-    row per program and the cross term is a (1,W)@(W,BC) row-matmul per
-    nibble.  Same packed feed, same permuted dim order.
+    brings its OWN candidate set (the ±h master-order windows gathered by
+    the fused search path), so there is no matmul to share between
+    queries.  Candidates arrive word-major, (Q, W, C) uint32: candidates on
+    the 128 lanes, packed words on sublanes.  The query and the centroid
+    table arrive split by nibble, (Q, 8, W, 1) and (8·L, W, 1), so nibble
+    s of every word lines up with its dims without any lane-offset slice.
+    Per nibble the kernel reconstructs a (BQ, W, BC) tile, and the cross
+    term and ‖r‖² are sums over the W sublanes.
 
 Tiling: grid (Q/BQ, C/BC); VMEM per step ≈ BQ·d·4 + BC·d (+ recon BC·d·4)
 + BQ·BC·4 ≈ 0.6 MB at (128, 128, d=384) — well inside 16 MB VMEM, sized so
@@ -39,8 +43,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 BQ = 128
 BC = 128
+# Windows kernel tiles: 8 queries × up to 512 candidates per step.
+WIN_BQ = 8
+WIN_BC = 512
 
 
 def _reconstruct(codes_i32: jax.Array, cents: jax.Array, levels: int) -> jax.Array:
@@ -85,23 +94,21 @@ def _qdist_packed_kernel(q_ref, c_ref, cent_ref, out_ref, *, levels: int):
 
 
 def _qdist_packed_windows_kernel(q_ref, c_ref, cent_ref, out_ref, *, levels: int):
-    q = q_ref[...]                       # (1, 8W) f32, permuted dim order
-    packed = c_ref[...][0]               # (1, BC, W) uint32 -> (BC, W)
-    cents = cent_ref[...]                # (8W, L) f32, permuted dim order
-    w = packed.shape[1]
-    acc = jnp.zeros((1, packed.shape[0]), jnp.float32)
-    rsq = jnp.zeros((packed.shape[0], 1), jnp.float32)
+    packed = c_ref[...]                  # (BQ, W, BC) uint32, word-major
+    acc = rsq = qsq = None
     for s in range(8):
         nib = ((packed >> jnp.uint32(4 * s)) & jnp.uint32(0xF)).astype(jnp.int32)
-        cent_s = jax.lax.dynamic_slice_in_dim(cents, s * w, w, axis=0)  # (W, L)
-        recon = _reconstruct(nib, cent_s, levels)  # (BC, W)
-        q_s = jax.lax.dynamic_slice_in_dim(q, s * w, w, axis=1)  # (1, W)
-        acc += jax.lax.dot_general(
-            q_s, recon, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        rsq += jnp.sum(recon * recon, axis=1, keepdims=True)
-    qsq = jnp.sum(q * q, axis=1, keepdims=True)
-    out_ref[...] = qsq - 2.0 * acc + rsq.T
+        recon = jnp.zeros(nib.shape, jnp.float32)
+        for l in range(levels):  # centroid column (W, 1) of nibble s, level l
+            recon = jnp.where(nib == l, cent_ref[s * levels + l][None], recon)
+        q_s = q_ref[:, s]                # (BQ, W, 1) dims 8·w + s
+        cross = jnp.sum(q_s * recon, axis=1)        # (BQ, BC)
+        r = jnp.sum(recon * recon, axis=1)          # (BQ, BC)
+        qq = jnp.sum(q_s * q_s, axis=1)             # (BQ, 1)
+        acc = cross if acc is None else acc + cross
+        rsq = r if rsq is None else rsq + r
+        qsq = qq if qsq is None else qsq + qq
+    out_ref[...] = qsq - 2.0 * acc + rsq
 
 
 def packed_dim_order(d: int) -> np.ndarray:
@@ -116,14 +123,13 @@ def packed_dim_order(d: int) -> np.ndarray:
     return (8 * ww + s).astype(np.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("levels", "interpret", "bq", "bc"))
+@functools.partial(jax.jit, static_argnames=("levels", "bq", "bc"))
 def qdist_u8_kernel(
     queries: jax.Array,
     codes: jax.Array,
     centroids: jax.Array,
     *,
     levels: int = 16,
-    interpret: bool = False,
     bq: int = BQ,
     bc: int = BC,
 ) -> jax.Array:
@@ -131,7 +137,7 @@ def qdist_u8_kernel(
     qn, d = queries.shape
     cn = codes.shape[0]
     grid = (qn // bq, cn // bc)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_qdist_u8_kernel, levels=levels),
         grid=grid,
         in_specs=[
@@ -141,18 +147,16 @@ def qdist_u8_kernel(
         ],
         out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, cn), jnp.float32),
-        interpret=interpret,
     )(queries, codes, centroids)
 
 
-@functools.partial(jax.jit, static_argnames=("levels", "interpret", "bq", "bc"))
+@functools.partial(jax.jit, static_argnames=("levels", "bq", "bc"))
 def qdist_packed_kernel(
     queries_perm: jax.Array,
     packed: jax.Array,
     centroids_perm: jax.Array,
     *,
     levels: int = 16,
-    interpret: bool = False,
     bq: int = BQ,
     bc: int = BC,
 ) -> jax.Array:
@@ -161,7 +165,7 @@ def qdist_packed_kernel(
     cn, w = packed.shape
     assert d == 8 * w, (d, w)
     grid = (qn // bq, cn // bc)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_qdist_packed_kernel, levels=levels),
         grid=grid,
         in_specs=[
@@ -171,38 +175,38 @@ def qdist_packed_kernel(
         ],
         out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, cn), jnp.float32),
-        interpret=interpret,
     )(queries_perm, packed, centroids_perm)
 
 
-@functools.partial(jax.jit, static_argnames=("levels", "interpret", "bc"))
+@functools.partial(jax.jit, static_argnames=("levels", "bq", "bc"))
 def qdist_packed_windows_kernel(
-    queries_perm: jax.Array,
+    queries_split: jax.Array,
     packed_windows: jax.Array,
-    centroids_perm: jax.Array,
+    centroids_split: jax.Array,
     *,
     levels: int = 16,
-    interpret: bool = False,
-    bc: int = BC,
+    bq: int = WIN_BQ,
+    bc: int = WIN_BC,
 ) -> jax.Array:
-    """Per-query candidate windows: (Q, 8W) f32 × (Q, C, W) uint32 -> (Q, C).
+    """Per-query candidate windows: (Q, 8, W, 1) f32 × (Q, W, C) uint32 ->
+    (Q, C) f32 d².
 
-    Grid walks (query, candidate-tile); queries/centroids pre-permuted by
-    ``packed_dim_order`` like :func:`qdist_packed_kernel`.
+    ``queries_split[q, s, w]`` is dim 8·w + s of query q and
+    ``centroids_split[s·L + l, w]`` is centroid ``l`` of that dim (the
+    layout ``pack_codes`` gives nibble s of word w).  Q must be a multiple
+    of ``bq`` and C of ``bc`` (ops.py pads).
     """
-    qn, d = queries_perm.shape
-    _, cn, w = packed_windows.shape
-    assert d == 8 * w, (d, w)
-    grid = (qn, cn // bc)
-    return pl.pallas_call(
+    qn, _, w, _ = queries_split.shape
+    cn = packed_windows.shape[2]
+    grid = (qn // bq, cn // bc)
+    return pallas_call(
         functools.partial(_qdist_packed_windows_kernel, levels=levels),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bc, w), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((d, levels), lambda i, j: (0, 0)),
+            pl.BlockSpec((bq, 8, w, 1), lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((bq, w, bc), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((8 * levels, w, 1), lambda i, j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bc), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, cn), jnp.float32),
-        interpret=interpret,
-    )(queries_perm, packed_windows, centroids_perm)
+    )(queries_split, packed_windows, centroids_split)

@@ -11,6 +11,8 @@ import numpy as np
 from repro.kernels.qdist.kernel import (
     BC,
     BQ,
+    WIN_BC,
+    WIN_BQ,
     packed_dim_order,
     qdist_packed_kernel,
     qdist_packed_windows_kernel,
@@ -32,14 +34,13 @@ def _pad_axis(x: jax.Array, m: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def qdist(
     queries: jax.Array,
     codes: jax.Array,
     centroids: jax.Array,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """Asymmetric squared-L2: fp32 queries vs uint8-coded database rows.
 
@@ -60,11 +61,11 @@ def qdist(
     q = jnp.pad(queries, ((0, (-qn) % BQ), (0, dp - d)))
     c = jnp.pad(codes, ((0, (-cn) % BC), (0, dp - d)))
     cent = jnp.pad(centroids, ((0, dp - d), (0, 0)))
-    out = qdist_u8_kernel(q, c, cent, levels=centroids.shape[1], interpret=interpret)
+    out = qdist_u8_kernel(q, c, cent, levels=centroids.shape[1])
     return out[:qn, :cn]
 
 
-@functools.partial(jax.jit, static_argnames=("d", "use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("d", "use_kernel"))
 def qdist_from_packed(
     queries: jax.Array,
     packed: jax.Array,
@@ -72,7 +73,6 @@ def qdist_from_packed(
     *,
     d: int,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """Packed-nibble codes variant — 0.5 B/dim HBM traffic on TPU.
 
@@ -95,12 +95,12 @@ def qdist_from_packed(
     cent = jnp.pad(centroids, ((0, dp - d), (0, 0)))
     order = jnp.asarray(packed_dim_order(dp))
     out = qdist_packed_kernel(
-        q[:, order], p, cent[order], levels=centroids.shape[1], interpret=interpret
+        q[:, order], p, cent[order], levels=centroids.shape[1]
     )
     return out[:qn, :cn]
 
 
-@functools.partial(jax.jit, static_argnames=("d", "use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("d", "use_kernel"))
 def qdist_windows_from_packed(
     queries: jax.Array,
     packed_windows: jax.Array,
@@ -108,7 +108,6 @@ def qdist_windows_from_packed(
     *,
     d: int,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """Per-query packed candidate sets — the fused stage-2 serving shape.
 
@@ -125,16 +124,22 @@ def qdist_windows_from_packed(
         return qdist_packed_windows_ref(queries, packed_windows, centroids, d=d)
     qn = queries.shape[0]
     _, cn, w = packed_windows.shape
-    # Pad packed width so 8·W is a lane multiple; nibble 0 + zero centroid
-    # columns contribute nothing.  Candidate tiles pad with all-zero rows
-    # whose (finite) distances are sliced away below.
-    wp = -(-w // 16) * 16
+    levels = centroids.shape[1]
+    # Pad the packed width to a sublane multiple (nibble 0 against zero
+    # centroid rows and zero query dims contributes nothing) and the
+    # candidates to whole tiles (all-zero rows whose finite distances are
+    # sliced away below).  Candidates go word-major (Q, W, C); query dims
+    # and centroids are split by nibble: dim 8·w + s -> [s, w].
+    wp = -(-w // 8) * 8
     dp = 8 * wp
-    q = jnp.pad(queries, ((0, 0), (0, dp - d)))
-    p = jnp.pad(packed_windows, ((0, 0), (0, (-cn) % BC), (0, wp - w)))
+    bc = min(WIN_BC, -(-cn // 128) * 128)
+    q = jnp.pad(queries, ((0, (-qn) % WIN_BQ), (0, dp - d)))
+    q = jnp.swapaxes(q.reshape(-1, wp, 8), 1, 2)[..., None]
+    p = jnp.pad(packed_windows, ((0, (-qn) % WIN_BQ), (0, (-cn) % bc),
+                                 (0, wp - w)))
+    p = jnp.swapaxes(p, 1, 2)
     cent = jnp.pad(centroids, ((0, dp - d), (0, 0)))
-    order = jnp.asarray(packed_dim_order(dp))
-    out = qdist_packed_windows_kernel(
-        q[:, order], p, cent[order], levels=centroids.shape[1], interpret=interpret
-    )
-    return out[:, :cn]
+    cent = jnp.transpose(cent.reshape(wp, 8, levels), (1, 2, 0))
+    cent = cent.reshape(8 * levels, wp, 1)
+    out = qdist_packed_windows_kernel(q, p, cent, levels=levels, bc=bc)
+    return out[:qn, :cn]

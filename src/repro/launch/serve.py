@@ -28,6 +28,7 @@ import numpy as np
 from repro import configs
 from repro.core.types import ForestConfig, SearchParams
 from repro.index import IndexConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.serve.engine import MaintenancePolicy
 from repro.serve.retrieval import RetrievalStore, knn_lm_mix
@@ -87,6 +88,7 @@ def main() -> None:
                          "alive this long after the workload finishes, so "
                          "an external scraper can read final counters")
     args = ap.parse_args()
+    enable_compile_cache()
 
     metrics_server = None
     if args.metrics_port is not None:

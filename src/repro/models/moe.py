@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -147,7 +147,7 @@ def moe_forward_ep(
         in_specs=(P(rules.batch, None, None), P(None, None), wspec,
                   P("model", None, fsdp), (wspec if w3 is not None else P())),
         out_specs=(P(rules.batch, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x, p["router"],
                   p["w1"], p["w2"], w3 if w3 is not None else jnp.zeros(()))

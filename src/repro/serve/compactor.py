@@ -122,6 +122,9 @@ def child_main(argv=None) -> int:
               file=sys.stderr)
         return 2
     in_dir, out_dir = args
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.checkpoint import atomic_write_json
     from repro.testing.faults import fault_point
 
@@ -183,15 +186,17 @@ def _child_env() -> Dict[str, str]:
     )
     import jax
 
-    if jax.default_backend() == "cpu":
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        flags = env.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" not in flags:
-            # a sharded bundle needs as many child devices as shards
-            env["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{jax.device_count()}"
-            ).strip()
+    # The child always runs on the CPU: on an accelerator the serving
+    # parent holds the chip, and a child that opened it would fail or hang.
+    # The handoff is a bundle on disk, so the platform is the child's own.
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = env.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" not in flags:
+        # a sharded bundle needs as many child devices as shards
+        env["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count="
+            f"{jax.device_count()}"
+        ).strip()
     return env
 
 

@@ -116,3 +116,46 @@ def test_quantizer_roundtrip_and_shared_msb():
     sk_direct = np.asarray(sketch.make_sketches(quant, jnp.asarray(data)))
     mismatch = (sk_codes != sk_direct).mean()
     assert mismatch < 1e-3  # boundary ties only
+
+
+def test_knn_merge_order_row_chunks_match_one_pass(monkeypatch):
+    """Past MERGE_CHUNK rows an order's candidates merge in row blocks (to
+    bound device scratch); the result, ragged tail included, is the one-pass
+    merge's."""
+    rng = np.random.default_rng(6)
+    n, w, k1, k2 = 2500, 3, 16, 12
+    sk = jnp.asarray(rng.integers(0, 2**32, (n, w), dtype=np.uint32))
+    order = jnp.asarray(rng.permutation(n).astype(np.int32))
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n))
+    best_id = jnp.asarray(rng.integers(-1, n, (n, k2)).astype(np.int32))
+    best_d = jnp.asarray(rng.integers(0, 90, (n, k2)).astype(np.int32))
+    one_pass = knn_graph.merge_order(best_id, best_d, order, rank, sk,
+                                     k1=k1, k2=k2)
+    monkeypatch.setattr(knn_graph, "MERGE_CHUNK", 1000)
+    knn_graph.merge_order.clear_cache()
+    chunked = knn_graph.merge_order(best_id, best_d, order, rank, sk,
+                                    k1=k1, k2=k2)
+    knn_graph.merge_order.clear_cache()
+    for a, b in zip(chunked, one_pass):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_lowrank_embeddings_match_per_row_reference():
+    """The generator's per-cluster products equal the per-row formula
+    centers[a] + noise * U[a] @ z, up to f32 summation order over r."""
+    n, d, c, r, noise = 3000, 48, 8, 16, 0.9
+    got = ann_datasets.lowrank_embeddings(n, d, n_clusters=c, r=r,
+                                          noise=noise, seed=3)
+    rng = np.random.default_rng(3)
+    centers = rng.normal(size=(c, d)).astype(np.float32)
+    centers /= np.linalg.norm(centers, axis=1, keepdims=True)
+    assign = rng.integers(0, c, n)
+    u = rng.normal(size=(c, d, r)).astype(np.float32)
+    u /= np.linalg.norm(u, axis=1, keepdims=True)
+    spec = ((1.0 + np.arange(r)) ** -0.5).astype(np.float32)
+    z = rng.normal(size=(n, r)).astype(np.float32) * spec
+    ref = np.stack([centers[a] + noise * (u[a] @ zi)
+                    for a, zi in zip(assign, z)])
+    ref /= np.linalg.norm(ref, axis=1, keepdims=True)
+    np.testing.assert_allclose(got, ref, rtol=0,
+                               atol=4 * r * np.finfo(np.float32).eps)

@@ -137,3 +137,32 @@ def test_perm_and_flip_change_order_but_not_set():
     )
     assert not np.array_equal(np.asarray(o1), np.asarray(o2))
     assert sorted(np.asarray(o2).tolist()) == list(range(512))
+
+
+def test_hilbert_keys_row_chunks_match_one_pass(monkeypatch):
+    """Past KEY_CHUNK rows the keys are computed in row blocks (to bound
+    device scratch); the blocks, ragged tail included, give the keys of
+    one pass."""
+    rng = np.random.default_rng(5)
+    pts = jnp.asarray(rng.normal(size=(2500, 24)).astype(np.float32))
+    lo, hi = jnp.full((24,), -4.0), jnp.full((24,), 4.0)
+    perm = jnp.asarray(rng.permutation(24).astype(np.int32))
+    flip = jnp.asarray(rng.integers(0, 2, 24).astype(bool))
+    kw = dict(bits=4, key_bits=80, lo=lo, hi=hi, perm=perm, flip=flip)
+    one_pass = hilbert.hilbert_keys(pts, **kw)
+    monkeypatch.setattr(hilbert, "KEY_CHUNK", 1000)
+    hilbert.hilbert_keys.clear_cache()
+    chunked = hilbert.hilbert_keys(pts, **kw)
+    hilbert.hilbert_keys.clear_cache()
+    np.testing.assert_array_equal(np.asarray(chunked), np.asarray(one_pass))
+
+
+@pytest.mark.parametrize("n", [1, 7, 300])
+def test_lexsort_words_matches_numpy_lexsort(n):
+    """The LSD loop of stable single-word sorts is the lexicographic order,
+    equal keys in index order (np.lexsort is stable)."""
+    rng = np.random.default_rng(n)
+    keys = rng.integers(0, 4, size=(n, 5)).astype(np.uint32)  # many ties
+    got = np.asarray(hilbert.lexsort_words(jnp.asarray(keys)))
+    ref = np.lexsort(tuple(keys[:, i] for i in range(4, -1, -1)))
+    np.testing.assert_array_equal(got, ref)

@@ -42,7 +42,7 @@ RNG = np.random.default_rng(0)
 def test_hamming_kernel_matches_ref(q, c, w):
     a = jnp.asarray(RNG.integers(0, 2**32, size=(q, w), dtype=np.uint32))
     b = jnp.asarray(RNG.integers(0, 2**32, size=(c, w), dtype=np.uint32))
-    got = hamming_matrix(a, b, use_kernel=True, interpret=True)
+    got = hamming_matrix(a, b, use_kernel=True)
     ref = hamming_matrix_ref(a, b)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -50,7 +50,7 @@ def test_hamming_kernel_matches_ref(q, c, w):
 def test_hamming_known_values():
     a = jnp.asarray(np.array([[0x0, 0xFFFFFFFF]], np.uint32))
     b = jnp.asarray(np.array([[0x0, 0xFFFFFFFF], [0xF, 0xFFFFFFFF], [0x0, 0x0]], np.uint32))
-    got = np.asarray(hamming_matrix(a, b, use_kernel=True, interpret=True))
+    got = np.asarray(hamming_matrix(a, b, use_kernel=True))
     np.testing.assert_array_equal(got, [[0, 4, 32]])
 
 
@@ -65,7 +65,7 @@ def test_hamming_kernel_property(q, c, w, seed):
     rng = np.random.default_rng(seed)
     a = jnp.asarray(rng.integers(0, 2**32, size=(q, w), dtype=np.uint32))
     b = jnp.asarray(rng.integers(0, 2**32, size=(c, w), dtype=np.uint32))
-    got = np.asarray(hamming_matrix(a, b, use_kernel=True, interpret=True))
+    got = np.asarray(hamming_matrix(a, b, use_kernel=True))
     ref = np.asarray(hamming_matrix_ref(a, b))
     np.testing.assert_array_equal(got, ref)
     # metric properties: symmetry on identical args, range
@@ -92,7 +92,7 @@ def test_qdist_u8_kernel_matches_ref(q, c, d):
     queries = jnp.asarray(RNG.normal(size=(q, d)).astype(np.float32))
     quant = quantize.fit(jnp.asarray(data), bits=4)
     codes = quantize.encode(quant, jnp.asarray(data))
-    got = qdist(queries, codes, quant.centroids, use_kernel=True, interpret=True)
+    got = qdist(queries, codes, quant.centroids, use_kernel=True)
     ref = qdist_u8_ref(queries, codes, quant.centroids)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
@@ -105,7 +105,7 @@ def test_qdist_packed_kernel_matches_ref(q, c, d):
     codes = quantize.encode(quant, jnp.asarray(data))
     packed = quantize.pack_codes(codes)
     got = qdist_from_packed(
-        queries, packed, quant.centroids, d=d, use_kernel=True, interpret=True
+        queries, packed, quant.centroids, d=d, use_kernel=True
     )
     ref = qdist_u8_ref(queries, codes, quant.centroids)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
@@ -120,7 +120,7 @@ def test_qdist_windows_kernel_matches_ref(q, c, d):
     codes = quantize.encode(quant, jnp.asarray(data))
     windows = jax.vmap(quantize.pack_codes)(codes.reshape(q, c, d))
     got = qdist_windows_from_packed(
-        queries, windows, quant.centroids, d=d, use_kernel=True, interpret=True
+        queries, windows, quant.centroids, d=d, use_kernel=True
     )
     per_query_ref = [
         np.asarray(
@@ -141,7 +141,7 @@ def test_qdist_zero_distance_to_self_centroids():
     codes = quantize.encode(quant, jnp.asarray(data))
     recon = quantize.decode(quant, codes)
     got = np.asarray(
-        qdist(recon, codes, quant.centroids, use_kernel=True, interpret=True)
+        qdist(recon, codes, quant.centroids, use_kernel=True)
     )
     np.testing.assert_allclose(np.diag(got), 0.0, atol=1e-4)
 
@@ -160,7 +160,7 @@ def test_qdist_property_nonneg_and_exact(q, c, d, seed):
     quant = quantize.fit(jnp.asarray(data), bits=4)
     codes = quantize.encode(quant, jnp.asarray(data))
     got = np.asarray(
-        qdist(queries, codes, quant.centroids, use_kernel=True, interpret=True)
+        qdist(queries, codes, quant.centroids, use_kernel=True)
     )
     ref = np.asarray(qdist_u8_ref(queries, codes, quant.centroids))
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
@@ -177,14 +177,14 @@ from repro.kernels.bitpack import pack_bits, pack_bits_ref  # noqa: E402
 @pytest.mark.parametrize("n,k", [(1, 32), (7, 100), (256, 128), (300, 448), (64, 31)])
 def test_bitpack_kernel_matches_ref(n, k):
     bits = jnp.asarray(RNG.integers(0, 2, size=(n, k), dtype=np.uint8))
-    got = pack_bits(bits, use_kernel=True, interpret=True)
+    got = pack_bits(bits, use_kernel=True)
     ref = pack_bits(bits, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_bitpack_msb_first():
     bits = jnp.zeros((1, 32), jnp.uint8).at[0, 0].set(1)
-    out = np.asarray(pack_bits(bits, use_kernel=True, interpret=True))
+    out = np.asarray(pack_bits(bits, use_kernel=True))
     assert out[0, 0] == 1 << 31
 
 
@@ -193,7 +193,7 @@ def test_bitpack_msb_first():
 def test_bitpack_property(n, k, seed):
     rng = np.random.default_rng(seed)
     bits = jnp.asarray(rng.integers(0, 2, size=(n, k), dtype=np.uint8))
-    got = np.asarray(pack_bits(bits, use_kernel=True, interpret=True))
+    got = np.asarray(pack_bits(bits, use_kernel=True))
     ref = np.asarray(pack_bits_ref(jnp.asarray(np.pad(
         np.asarray(bits), ((0, 0), (0, (-k) % 32))))))[:, : -(-k // 32)]
     np.testing.assert_array_equal(got, ref)

@@ -26,7 +26,7 @@ def test_hamming_rows_kernel_matches_oracle(q, k, w):
     # integer popcounts have no accumulation-order freedom: exact equality
     a = jnp.asarray(RNG.integers(0, 2**32, (q, w), dtype=np.uint32))
     c = jnp.asarray(RNG.integers(0, 2**32, (q, k, w), dtype=np.uint32))
-    got = hamming_rows(a, c, use_kernel=True, interpret=True)
+    got = hamming_rows(a, c, use_kernel=True)
     ref = hamming_rows(a, c, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 

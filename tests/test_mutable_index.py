@@ -455,6 +455,30 @@ def test_from_index_adoption(dataset):
     assert (new_ids >= 500).all()
 
 
+def test_segment_k_inflation_is_pow2_bucketed(dataset, monkeypatch):
+    """A segment is searched for k + pow2(dead): delete counts in one bucket
+    reuse one compiled search instead of recompiling per delete batch."""
+    data, queries = dataset
+    base = HilbertIndex.build(jnp.asarray(data[:500]), CFG)
+    mut = MutableHilbertIndex.from_index(base, buffer_capacity=64)
+    seen = []
+    real = HilbertIndex.search
+
+    def spy(self, q, params=None, **kw):
+        seen.append(params.k)
+        return real(self, q, params, **kw)
+
+    monkeypatch.setattr(HilbertIndex, "search", spy)
+    ks = []
+    for dead in (0, 5, 8, 9, 16):
+        mut.delete(np.arange(dead)[np.asarray(mut._alive[:dead])])
+        seen.clear()
+        hits, _ = mut.search(queries, SP)
+        assert not np.isin(np.asarray(hits), np.arange(dead)).any()
+        ks.append(seen[-1])
+    assert ks == [SP.k, SP.k + 8, SP.k + 8, SP.k + 16, SP.k + 16]
+
+
 # -- reporting / repr / defaults --------------------------------------------
 
 
@@ -547,3 +571,17 @@ def test_retrieval_store_append_delete(tmp_path, dataset):
     assert np.array_equal(np.asarray(ids4), np.asarray(ids1))
     loaded.append(jnp.asarray(data[:10]), jnp.asarray(vals[:10]))
     assert loaded.index.n_live == 1010
+
+
+def test_buffer_exact_search_matmul_runs_at_highest_precision():
+    """The write buffer's exact search must not inherit a TPU's one-pass
+    bf16 default for f32 matmuls: every dot it lowers asks for HIGHEST."""
+    from repro.core import search as search_lib
+
+    text = search_lib.brute_force_topk.lower(
+        jnp.zeros((4, 8), jnp.float32), jnp.zeros((16, 8), jnp.float32),
+        jnp.ones((16,), bool), k=3,
+    ).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert dots and all("precision = [HIGHEST, HIGHEST]" in line
+                        for line in dots), dots
