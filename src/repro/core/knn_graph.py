@@ -89,20 +89,28 @@ def merge_order(best_id, best_dist, order, rank, sketches, *, k1, k2):
 
 
 def _merge_rows(rows, best_id, best_dist, order, rank, sketches, *, k1, k2):
+    """One block of rows, its steps under the named scopes
+    ``merge_order.candidates``, ``merge_order.hamming`` and
+    ``merge_order.merge``."""
     n = order.shape[0]
     half = k1 // 2
-    # ±half window around each point's rank, self excluded by distance mask.
-    deltas = jnp.concatenate(
-        [jnp.arange(-half, 0, dtype=jnp.int32), jnp.arange(1, k1 - half + 1, dtype=jnp.int32)]
-    )  # k1 offsets, 0 excluded
-    pos = rank[rows][:, None] + deltas[None, :]
-    pos = jnp.clip(pos, 0, n - 1)
-    cand = order[pos]  # (rows, k1) ids
-    hd = sketch.hamming_distance(sketches[rows][:, None, :], sketches[cand])
-    self_mask = cand == rows[:, None]
-    hd = jnp.where(self_mask, _INF, hd)
-
-    return search._merge_topk_dedup(best_id, best_dist, cand, hd, k2)
+    with jax.named_scope("merge_order.candidates"):
+        # ±half window around each point's rank, self excluded by distance
+        # mask.
+        deltas = jnp.concatenate(
+            [jnp.arange(-half, 0, dtype=jnp.int32),
+             jnp.arange(1, k1 - half + 1, dtype=jnp.int32)]
+        )  # k1 offsets, 0 excluded
+        pos = rank[rows][:, None] + deltas[None, :]
+        pos = jnp.clip(pos, 0, n - 1)
+        cand = order[pos]  # (rows, k1) ids
+    with jax.named_scope("merge_order.hamming"):
+        hd = sketch.hamming_distance(sketches[rows][:, None, :],
+                                     sketches[cand])
+        self_mask = cand == rows[:, None]
+        hd = jnp.where(self_mask, _INF, hd)
+    with jax.named_scope("merge_order.merge"):
+        return search._merge_topk_dedup(best_id, best_dist, cand, hd, k2)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -181,20 +181,28 @@ def stage1_tree_merge(
     k2,
     use_kernels=False,
 ):
-    """One tree's stage-1: candidates → Hamming filter → merge into top-k2."""
-    cand_ids = forest_lib.tree_candidates(
-        queries, order, directory, lo, hi, perm, flip,
-        bits=bits, key_bits=key_bits, leaf_size=leaf_size, k1=k1,
-    )  # (Q, k1)
-    mpos = master_rank[cand_ids]  # (Q, k1) master positions
-    csk = sketches_master[mpos]  # (Q, k1, Ws)
-    if use_kernels:
-        from repro.kernels.hamming import hamming_rows
+    """One tree's stage-1: candidates → Hamming filter → merge into top-k2.
 
-        hd = hamming_rows(qsketches, csk, use_kernel=True)  # (Q, k1)
-    else:
-        hd = sketch.hamming_distance(qsketches[:, None, :], csk)  # (Q, k1)
-    return _merge_topk_dedup(best_pos, best_dist, mpos, hd, k2)
+    Each step runs under a ``stage1.*`` named scope, which a device trace
+    reports as the op's ``tf_op``.
+    """
+    with jax.named_scope("stage1.candidates"):
+        cand_ids = forest_lib.tree_candidates(
+            queries, order, directory, lo, hi, perm, flip,
+            bits=bits, key_bits=key_bits, leaf_size=leaf_size, k1=k1,
+        )  # (Q, k1)
+    with jax.named_scope("stage1.sketch_gather"):
+        mpos = master_rank[cand_ids]  # (Q, k1) master positions
+        csk = sketches_master[mpos]  # (Q, k1, Ws)
+    with jax.named_scope("stage1.hamming"):
+        if use_kernels:
+            from repro.kernels.hamming import hamming_rows
+
+            hd = hamming_rows(qsketches, csk, use_kernel=True)  # (Q, k1)
+        else:
+            hd = sketch.hamming_distance(qsketches[:, None, :], csk)
+    with jax.named_scope("stage1.merge"):
+        return _merge_topk_dedup(best_pos, best_dist, mpos, hd, k2)
 
 
 def _expand_windows(best_pos, n: int, h: int):
@@ -291,26 +299,31 @@ def stage2_packed_windows(
     ``repro.kernels.qdist.qdist_windows_from_packed``: the Pallas kernel
     when ``use_kernels`` (Mosaic on TPU, see ``repro.kernels.platform``),
     else a packed XLA path that unpacks losslessly — bit-identical to
-    :func:`stage2_expand_rank` on the same candidates.
+    :func:`stage2_expand_rank` on the same candidates.  Its steps run under
+    the named scopes ``stage2.windows``, ``stage2.adc`` and
+    ``stage2.rank``.
     """
     n = master_order.shape[0]
     d = quant.centroids.shape[0]
     qn, k2 = best_pos.shape
-    starts, pos, window = _expand_windows(best_pos, n, h)
-    win = _window_slices(codes_packed, starts, window)  # (Q, k2, window, W)
-    win = win.reshape(qn, k2 * window, codes_packed.shape[1])
-    if use_kernels:
-        from repro.kernels.qdist import qdist_windows_from_packed
+    with jax.named_scope("stage2.windows"):
+        starts, pos, window = _expand_windows(best_pos, n, h)
+        win = _window_slices(codes_packed, starts, window)  # (Q, k2, window, W)
+        win = win.reshape(qn, k2 * window, codes_packed.shape[1])
+    with jax.named_scope("stage2.adc"):
+        if use_kernels:
+            from repro.kernels.qdist import qdist_windows_from_packed
 
-        d2 = qdist_windows_from_packed(
-            queries, win, quant.centroids, d=d, use_kernel=True,
+            d2 = qdist_windows_from_packed(
+                queries, win, quant.centroids, d=d, use_kernel=True,
+            )
+        else:
+            d2 = quantize.adc_distance_packed(quant, queries, win, d=d)
+    with jax.named_scope("stage2.rank"):
+        valid = jnp.broadcast_to((best_pos >= 0)[:, :, None], pos.shape)
+        return _dedup_rank_topk(
+            pos.reshape(qn, -1), d2, valid.reshape(qn, -1), master_order, k
         )
-    else:
-        d2 = quantize.adc_distance_packed(quant, queries, win, d=d)
-    valid = jnp.broadcast_to((best_pos >= 0)[:, :, None], pos.shape)
-    return _dedup_rank_topk(
-        pos.reshape(qn, -1), d2, valid.reshape(qn, -1), master_order, k
-    )
 
 
 @functools.partial(

@@ -39,6 +39,7 @@ from repro.core import search as search_lib
 from repro.core.types import GraphParams, SearchParams
 from repro.index.config import IndexConfig
 from repro.obs.dispatch import dispatch_scope
+from repro.obs.registry import default_registry
 from repro.obs.trace import span
 
 __all__ = [
@@ -596,7 +597,9 @@ def build_with_timings(
     """Build an index and return per-phase wall times (paper §3.2 split).
 
     Phases: ``quantization`` (fit+encode), ``sketches``, ``forest`` (the
-    dominant cost — n_trees Hilbert sorts), ``master_sort``.
+    dominant cost — n_trees Hilbert sorts), ``master_sort``.  Each phase's
+    time is also recorded, in ms, into the registry's
+    ``index_build_ms{phase=...}``.
 
     ``quant`` may supply a pre-fit quantizer instead of fitting one from
     ``points``.  The sharded facade builds every shard with ONE globally
@@ -648,6 +651,10 @@ def build_with_timings(
         )
         jax.block_until_ready(master_order)
     timings["master_sort"] = time.time() - t0
+    reg = default_registry()
+    for phase, seconds in timings.items():
+        reg.latency("index_build_ms", capacity=1024, phase=phase).record(
+            1000.0 * seconds)
 
     index = HilbertIndex(
         config=config,

@@ -44,6 +44,7 @@ def pack_bits_kernel(
     grid = (n // bn, w // bw)
     return pallas_call(
         _pack_kernel,
+        name="pack_bits",
         grid=grid,
         in_specs=[pl.BlockSpec((bn, bw * 32), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bn, bw), lambda i, j: (i, j)),

@@ -72,6 +72,7 @@ def hamming_matrix_kernel(
     grid = (qn // bq, cn // bc)
     return pallas_call(
         _hamming_kernel,
+        name="hamming_matrix",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, w), lambda i, j: (i, 0)),
@@ -106,6 +107,7 @@ def hamming_rows_kernel(
     grid = (qn // bq, k // bk)
     return pallas_call(
         _hamming_rows_kernel,
+        name="hamming_rows",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, w, 1), lambda i, j: (i, 0, 0)),

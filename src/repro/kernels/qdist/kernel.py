@@ -139,6 +139,7 @@ def qdist_u8_kernel(
     grid = (qn // bq, cn // bc)
     return pallas_call(
         functools.partial(_qdist_u8_kernel, levels=levels),
+        name="qdist_u8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
@@ -167,6 +168,7 @@ def qdist_packed_kernel(
     grid = (qn // bq, cn // bc)
     return pallas_call(
         functools.partial(_qdist_packed_kernel, levels=levels),
+        name="qdist_packed",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
@@ -201,6 +203,7 @@ def qdist_packed_windows_kernel(
     grid = (qn // bq, cn // bc)
     return pallas_call(
         functools.partial(_qdist_packed_windows_kernel, levels=levels),
+        name="qdist_packed_windows",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, 8, w, 1), lambda i, j: (i, 0, 0, 0)),

@@ -3,8 +3,9 @@
 The subsystem the rest of the library reports into, and the one place
 operators read from:
 
-* :mod:`repro.obs.trace` — hierarchical spans (wall + device time),
-  bounded buffer, Chrome-trace export.  Off by default; ``enable()``.
+* :mod:`repro.obs.trace` — hierarchical spans (wall time), bounded
+  buffer, Chrome-trace export, mirrored into ``jax.profiler`` captures.
+  Off by default; ``enable()``.
 * :mod:`repro.obs.registry` — process-global counters / gauges /
   exact-percentile latency recorders; JSON snapshot + Prometheus text.
 * :mod:`repro.obs.dispatch` — per-site dispatch counters and the

@@ -16,15 +16,20 @@ every call, so instrumentation sites don't coordinate creation order.
 ``LatencyRecorder`` keeps raw samples (bounded ring) so percentiles are
 exact over the retained window rather than histogram-bucketed — tail
 latency (p999) is the whole point of the serving engine, so the last
-thing the metrics layer should do is quantize it away.  It lives here
-(not ``serve/metrics.py``) because core/index instrumentation needs it
-without importing the serving layer; ``serve.metrics`` re-exports it.
+thing the metrics layer should do is quantize it away.  Each sample
+carries its ``time.perf_counter()`` stamp, so a reader can ask about a
+span of time (``samples(since=t)``: the last minute, a benchmark's
+measured window) rather than about the last ``capacity`` samples.  It
+lives here (not ``serve/metrics.py``) because core/index instrumentation
+needs it without importing the serving layer; ``serve.metrics``
+re-exports it.
 """
 
 from __future__ import annotations
 
 import json
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -132,19 +137,24 @@ class Gauge:
 
 
 class LatencyRecorder:
-    """Bounded ring of latency samples with exact percentile snapshots."""
+    """Bounded ring of time-stamped latency samples with exact percentile
+    snapshots."""
 
     def __init__(self, capacity: int = 65536):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self._cap = int(capacity)
         self._buf = np.zeros((self._cap,), np.float64)
+        self._at = np.zeros((self._cap,), np.float64)  # perf_counter stamps
         self._n = 0          # total ever recorded
         self._lock = threading.Lock()
 
     def record(self, latency_ms: float) -> None:
+        at = time.perf_counter()
         with self._lock:
-            self._buf[self._n % self._cap] = float(latency_ms)
+            i = self._n % self._cap
+            self._buf[i] = float(latency_ms)
+            self._at[i] = at
             self._n += 1
 
     @property
@@ -162,12 +172,37 @@ class LatencyRecorder:
         with self._lock:
             return self._n, self._buf[: min(self._n, self._cap)].copy()
 
-    def samples(self) -> np.ndarray:
-        """Copy of the retained window (oldest-sample order not preserved)."""
-        return self._consistent()[1]
+    def samples(self, since: Optional[float] = None,
+                before: Optional[float] = None) -> np.ndarray:
+        """Copy of the retained samples, oldest first.
 
-    def snapshot(self, points=(50.0, 99.0, 99.9)) -> Dict[str, float]:
-        n, s = self._consistent()
+        ``since`` / ``before`` keep those recorded at or after / strictly
+        before a ``time.perf_counter()`` reading.
+        """
+        with self._lock:
+            n, k = self._n, min(self._n, self._cap)
+            oldest = n % self._cap if n > self._cap else 0
+            vals = np.roll(self._buf[:k], -oldest)     # copies
+            at = np.roll(self._at[:k], -oldest)
+        keep = np.ones(k, bool)
+        if since is not None:
+            keep &= at >= since
+        if before is not None:
+            keep &= at < before
+        return vals[keep]
+
+    def snapshot(self, points=(50.0, 99.0, 99.9),
+                 since: Optional[float] = None) -> Dict[str, float]:
+        """Percentiles, mean and max of the retained samples.
+
+        ``count`` is every sample ever recorded, or with ``since`` the
+        samples recorded from then on that the ring still holds.
+        """
+        if since is None:
+            n, s = self._consistent()
+        else:
+            s = self.samples(since=since)
+            n = s.size
         out = percentiles(s, points)
         out["count"] = float(n)
         if s.size:
@@ -227,6 +262,12 @@ class MetricsRegistry:
             elif not isinstance(m, LatencyRecorder):
                 raise TypeError(f"{name}{labels} registered as {type(m).__name__}")
             return m
+
+    def find(self, name: str, **labels: str) -> Optional[Any]:
+        """The metric registered under ``name`` and ``labels``, or ``None``;
+        unlike the get-or-create methods it never registers one."""
+        with self._lock:
+            return self._metrics.get(self._key(name, labels))
 
     def replace_latency(self, name: str, capacity: int = 65536,
                         **labels: str) -> LatencyRecorder:

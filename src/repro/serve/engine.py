@@ -405,12 +405,13 @@ class RetrievalEngine:
         #   _state_lock < serve-read < serve-write < _maint_lock
         self._state_lock = threading.Lock()   # epoch pointer + write log
         reg = default_registry()
-        _rw_read = reg.latency("engine_rwlock_read_wait_ms", capacity=4096)
-        _rw_write = reg.latency("engine_rwlock_write_wait_ms", capacity=4096)
+        rw_ms = {
+            kind: reg.latency(f"engine_rwlock_{name}_ms", capacity=4096)
+            for kind, name in (("read", "read_wait"), ("write", "write_wait"),
+                               ("hold", "write_hold"))
+        }
         self._serve_lock = ReadWriteLock(     # searches share, writes exclude
-            observer=lambda kind, ms: (
-                _rw_write if kind == "write" else _rw_read
-            ).record(ms)
+            observer=lambda kind, ms: rw_ms[kind].record(ms)
         )
         self._maint_lock = threading.Lock()   # one maintenance cycle at a time
         # one representative batch per (params, pow2 dispatch bucket) seen,
@@ -650,7 +651,7 @@ class RetrievalEngine:
         first (they share the read side), and no search observes a
         half-applied insert or a mid-seal segment list.
         """
-        with self._serve_lock.write_locked():
+        with self._serve_lock.write_locked(), span("engine.insert") as sp:
             index = self.index
             if not hasattr(index, "insert"):
                 raise TypeError(
@@ -659,6 +660,7 @@ class RetrievalEngine:
                 )
             self._check_writable()
             pts = np.asarray(jax.device_get(points), np.float32)
+            sp.set(rows=int(pts.shape[0]))
             vals = (
                 None if values is None
                 else np.asarray(jax.device_get(values)).copy()
@@ -678,7 +680,7 @@ class RetrievalEngine:
 
     def delete(self, ids) -> int:
         """Tombstone external ids on the serving index (logged like insert)."""
-        with self._serve_lock.write_locked():
+        with self._serve_lock.write_locked(), span("engine.delete") as sp:
             index = self.index
             if not hasattr(index, "delete"):
                 raise TypeError(
@@ -687,6 +689,7 @@ class RetrievalEngine:
                 )
             self._check_writable()
             idn = np.asarray(jax.device_get(ids)).copy()
+            sp.set(rows=int(idn.size))
             try:
                 n = index.delete(idn)
             except WalWriteError as e:

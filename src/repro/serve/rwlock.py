@@ -23,9 +23,10 @@ Semantics:
   side raises: two upgrading readers would deadlock symmetrically, so
   the hierarchy is enforced instead of discovered.
 * **Observable** — :meth:`stats` exposes acquisition counts, cumulative
-  wait and write-hold times; an optional ``observer(kind, wait_ms)``
-  callback lets the engine stream contention waits into the metrics
-  registry (``engine_rwlock_{read,write}_wait_ms``).
+  wait and write-hold times; an optional ``observer(kind, ms)`` callback
+  lets the engine stream each wait (``kind`` ``"read"`` / ``"write"``)
+  and each exclusive hold, on its release (``"hold"``), into the metrics
+  registry (``engine_rwlock_{read_wait,write_wait,write_hold}_ms``).
 
 The lock hierarchy this slots into (never acquire leftward while holding
 rightward): engine state lock < serve READ < serve WRITE < maintenance
@@ -138,12 +139,14 @@ class ReadWriteLock:
             if self._writer != me:
                 raise RuntimeError("release_write by a non-holder")
             self._write_depth -= 1
-            if self._write_depth == 0:
-                self._writer = None
-                self._write_held_ms += 1000.0 * (
-                    time.perf_counter() - self._write_t0
-                )
-                self._cv.notify_all()
+            if self._write_depth:
+                return
+            self._writer = None
+            held = 1000.0 * (time.perf_counter() - self._write_t0)
+            self._write_held_ms += held
+            self._cv.notify_all()
+        if self._observer is not None:
+            self._observer("hold", held)
 
     # -- context managers ----------------------------------------------------
 

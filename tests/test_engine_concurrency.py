@@ -188,6 +188,20 @@ def test_rwlock_stats_accounting():
     assert s["write_held_ms"] >= 5.0
 
 
+def test_rwlock_observer_sees_waits_and_each_outer_hold():
+    seen = []
+    lock = ReadWriteLock(observer=lambda kind, ms: seen.append((kind, ms)))
+    with lock.write_locked():
+        with lock.write_locked(), lock.read_locked():
+            pass                        # re-entrant: not a hold of its own
+        time.sleep(0.01)
+    with lock.read_locked():
+        pass
+    assert [k for k, _ in seen] == ["write", "hold", "read"]
+    assert seen[1][1] >= 5.0
+    assert abs(seen[1][1] - lock.stats()["write_held_ms"]) < 1e-9
+
+
 # -- read-path purity --------------------------------------------------------
 
 

@@ -137,6 +137,42 @@ def test_disabled_tracer_is_noop_and_enable_preserves_buffer():
         obs.default_tracer().enabled = prev
 
 
+def test_disabled_span_without_capture_is_the_shared_noop():
+    from repro.obs.trace import _NOOP
+
+    assert Tracer(enabled=False).span("engine.batch", rows=3) is _NOOP
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_lands_on_profiler_host_plane(tmp_path, enabled):
+    """Under a profiler capture a span annotates the host plane, attrs as
+    metadata, whether or not the tracer records it too."""
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(enabled=enabled)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("engine.batch", rows=3) as s:
+            s.set(epoch=7)
+            jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    found = [
+        (plane.name, dict(e.stats))
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines for e in line.events
+        if e.name == "engine.batch"
+    ]
+    assert len(found) == 1
+    plane, stats = found[0]
+    assert plane.startswith("/host:")
+    assert stats["rows"] == 3 and stats["epoch"] == 7
+    assert [x.name for x in tr.spans()] == (["engine.batch"] if enabled
+                                            else [])
+
+
 def test_span_buffer_is_bounded():
     tr = Tracer(capacity=4, enabled=True)
     for i in range(10):
@@ -218,6 +254,32 @@ def test_latency_recorder_consistent_snapshot_under_writers():
             t.join()
     n, window = rec._consistent()
     assert window.size == min(n, 10_000)
+
+
+def test_latency_recorder_samples_since_keeps_later_samples():
+    rec = LatencyRecorder(capacity=4)
+    for v in (1.0, 2.0):
+        rec.record(v)
+    t = time.perf_counter()
+    for v in (3.0, 4.0, 5.0):
+        rec.record(v)
+    # the ring has wrapped: oldest first, 1.0 evicted
+    np.testing.assert_array_equal(rec.samples(), [2.0, 3.0, 4.0, 5.0])
+    np.testing.assert_array_equal(rec.samples(since=t), [3.0, 4.0, 5.0])
+    np.testing.assert_array_equal(rec.samples(before=t), [2.0])
+    assert rec.samples(since=time.perf_counter()).size == 0
+    snap = rec.snapshot(points=(50.0,), since=t)
+    assert snap == {"p50": 4.0, "count": 3.0, "mean": 4.0, "max": 5.0}
+    assert rec.snapshot()["count"] == 5.0
+
+
+def test_registry_find_never_registers():
+    reg = MetricsRegistry()
+    assert reg.find("lat_ms", phase="a") is None
+    rec = reg.latency("lat_ms", phase="a")
+    assert reg.find("lat_ms", phase="a") is rec
+    assert reg.find("lat_ms") is None
+    assert reg.snapshot() == {'lat_ms{phase="a"}': {"count": 0.0}}
 
 
 def test_registry_get_or_create_and_type_guard():
@@ -498,6 +560,30 @@ def test_step_mode_bit_identical_with_observability_enabled(
     snap = default_registry().snapshot()
     assert snap["engine_completed_total"] >= len(tickets)
     assert not np.isnan(snap["engine_recall_at_k"])
+
+
+def test_step_mode_records_lock_waits_holds_and_build_phases(dataset):
+    """The engine's writes record their exclusive holds, its searches
+    their read waits, and a build each phase, all stamped after ``t0``."""
+    data, queries = dataset
+    reg = default_registry()
+    t0 = time.perf_counter()
+    index = MutableHilbertIndex.from_index(
+        HilbertIndex.build(data[:1500], config=CFG), buffer_capacity=1024)
+    for phase in ("quantization", "sketches", "forest", "master_sort"):
+        ms = reg.find("index_build_ms", phase=phase).samples(since=t0)
+        assert ms.size >= 1 and np.all(ms >= 0.0), phase
+    eng = RetrievalEngine(index, SP, max_batch=16)
+    eng.insert(data[1500:1600])
+    eng.delete(np.arange(10))
+    eng.submit(queries[:8])
+    while eng.step():
+        pass
+    holds = reg.find("engine_rwlock_write_hold_ms").samples(since=t0)
+    waits = reg.find("engine_rwlock_read_wait_ms").samples(since=t0)
+    assert holds.size >= 2 and np.all(holds > 0.0)
+    assert waits.size >= 1 and np.all(waits >= 0.0)
+    assert reg.find("engine_search_ms").samples(since=t0).size >= 1
 
 
 # -- export surface (HTTP) ---------------------------------------------------

@@ -12,7 +12,12 @@ only one process may hold the TPU library, and the suite runs under several
 workers that all import this file.
 """
 
+import base64
+import contextlib
+import json
 import os
+import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -95,11 +100,12 @@ def test_qdist_windows_compiles_for_v5e(one_chip):
     )
 
 
-def _compile_search_chunk(sharding, use_kernels):
-    """The fused single-device search chunk at the default query chunk."""
+def _lower_search_chunk(sharding, use_kernels, *, n=N, trees=None,
+                        q=QUERY_CHUNK, row=ROW1):
+    """The fused single-device search chunk over ``n`` rows, lowered."""
     fcfg = pubmed23.FOREST
-    t = fcfg.n_trees
-    n_dir = -(-N // fcfg.leaf_size)
+    t = trees or fcfg.n_trees
+    n_dir = -(-n // fcfg.leaf_size)
     key_w = -(-fcfg.key_bits // 32)
 
     def chunk(queries, forest, master_rank, sketches, codes, master_order,
@@ -109,7 +115,7 @@ def _compile_search_chunk(sharding, use_kernels):
             forest.perms, forest.flips, master_rank, sketches, codes,
             master_order, quant,
             bits=fcfg.bits, key_bits=fcfg.key_bits, leaf_size=fcfg.leaf_size,
-            k1=ROW1.k1, k2=ROW1.k2, h=ROW1.h, k=ROW1.k,
+            k1=row.k1, k2=row.k2, h=row.h, k=row.k,
             use_kernels=use_kernels,
         )
 
@@ -117,7 +123,7 @@ def _compile_search_chunk(sharding, use_kernels):
     forest = forest_lib.HilbertForest(
         perms=_spec((t, D), jnp.int32, s),
         flips=_spec((t, D), jnp.bool_, s),
-        orders=_spec((t, N), jnp.int32, s),
+        orders=_spec((t, n), jnp.int32, s),
         directories=_spec((t, n_dir, key_w), jnp.uint32, s),
         lo=_spec((D,), jnp.float32, s),
         hi=_spec((D,), jnp.float32, s),
@@ -126,11 +132,16 @@ def _compile_search_chunk(sharding, use_kernels):
         boundaries=_spec((D, 15), jnp.float32, s),
         centroids=_spec((D, 16), jnp.float32, s),
     )
-    compiled = jax.jit(chunk).lower(
-        _spec((QUERY_CHUNK, D), jnp.float32, s), forest,
-        _spec((N,), jnp.int32, s), _spec((N, W_SKETCH), jnp.uint32, s),
-        _spec((N, W_CODES), jnp.uint32, s), _spec((N,), jnp.int32, s), quant,
-    ).compile()
+    return jax.jit(chunk).lower(
+        _spec((q, D), jnp.float32, s), forest,
+        _spec((n,), jnp.int32, s), _spec((n, W_SKETCH), jnp.uint32, s),
+        _spec((n, W_CODES), jnp.uint32, s), _spec((n,), jnp.int32, s), quant,
+    )
+
+
+def _compile_search_chunk(sharding, use_kernels):
+    """The fused single-device search chunk at the default query chunk."""
+    compiled = _lower_search_chunk(sharding, use_kernels).compile()
     # The chunk's scratch must fit a 16 GiB v5e beside two resident 2^20-row
     # indexes (an epoch swap holds the old and the new one).
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -163,3 +174,101 @@ def test_hilbert_keys_compile_for_v5e_without_window_scans(one_chip):
         _spec((D,), jnp.int32, one_chip), _spec((D,), jnp.bool_, one_chip),
     ).compile()
     assert "reduce-window" not in compiled.as_text()
+
+
+# -- stage names: metadata only ------------------------------------------------
+
+STAGE_SCOPES = {
+    "chunk": ("stage1.candidates", "stage1.sketch_gather", "stage1.hamming",
+              "stage1.merge", "stage2.windows", "stage2.adc", "stage2.rank"),
+    "merge_order": ("merge_order.candidates", "merge_order.hamming",
+                    "merge_order.merge"),
+}
+_KERNEL_LINE = re.compile(
+    r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"')
+
+
+def _kernel_text(body_b64: str) -> str:
+    """A Mosaic kernel's module as text, without its source locations and
+    with its symbol (the kernel's ``name=``) replaced."""
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    with ir.Context() as ctx, ir.Location.unknown():
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        text = ir.Module.parse(base64.b64decode(body_b64)).operation.get_asm(
+            enable_debug_info=False)
+    return re.sub(r"^module @\S+", "module @kernel", text)
+
+
+def _strip_names(hlo: str) -> str:
+    """Optimized HLO text without op metadata, the stack-frame tables that
+    metadata points into, and the kernels' names: the instruction name a
+    ``pallas_call(name=)`` gives and the symbol inside the kernel body."""
+    lines, skip = [], False
+    for line in hlo.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            skip = True
+        elif skip:
+            skip = bool(line.strip())
+        else:
+            lines.append(re.sub(r", metadata=\{[^}]*\}", "", line))
+    text = "\n".join(lines)
+    kernels = [m.group(1) for m in map(_KERNEL_LINE.match, lines) if m]
+    for i, name in enumerate(kernels):
+        text = re.sub(r"%" + re.escape(name) + r"(?![\w.\-])",
+                      f"%kernel{i}", text)
+    return re.sub(r'"body":"([^"]*)"',
+                  lambda m: json.dumps({"kernel": _kernel_text(m.group(1))}),
+                  text)
+
+
+def _compile_small(sharding, which):
+    """The fused chunk (Pallas route) or ``merge_order`` at a small shape
+    of the paper's widths; the optimized HLO text."""
+    n, s = 4096, sharding
+    if which == "chunk":
+        return _lower_search_chunk(
+            s, True, n=n, trees=4, q=64,
+            row=SimpleNamespace(k1=64, k2=32, h=2, k=10),
+        ).compile().as_text()
+    from repro.core import knn_graph
+
+    return jax.jit(lambda a, b, o, r, sk: knn_graph.merge_order(
+        a, b, o, r, sk, k1=96, k2=60)).lower(
+        _spec((n, 60), jnp.int32, s), _spec((n, 60), jnp.int32, s),
+        _spec((n,), jnp.int32, s), _spec((n,), jnp.int32, s),
+        _spec((n, W_SKETCH), jnp.uint32, s)).compile().as_text()
+
+
+@pytest.mark.parametrize("which", sorted(STAGE_SCOPES))
+def test_stage_names_change_only_metadata(one_chip, monkeypatch, which):
+    """Each stage scope reaches the optimized program's ``op_name``
+    metadata, and the program with its names stripped is the one compiled
+    with no scopes and no kernel names at all."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import platform
+
+    def scoped(text, scope):
+        return re.search(r'op_name="[^"]*/' + re.escape(scope) + "/", text)
+
+    jax.clear_caches()
+    named = _compile_small(one_chip, which)
+    for scope in STAGE_SCOPES[which]:
+        assert scoped(named, scope), scope
+    if which == "chunk":
+        assert "%hamming_rows" in named and "%qdist_packed_windows" in named
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(platform, "pl", SimpleNamespace(
+        pallas_call=lambda kernel, name=None, **kw: pl.pallas_call(
+            kernel, **kw)))
+    jax.clear_caches()
+    plain = _compile_small(one_chip, which)
+    jax.clear_caches()
+    assert not any(scoped(plain, scope) for scope in STAGE_SCOPES[which])
+    assert _strip_names(named) == _strip_names(plain)
