@@ -24,6 +24,14 @@ Implementation notes vs the pseudocode:
     gathers over the sorted copies).
   * Duplicates (same point from several trees / overlapping windows) are
     deduped during the merge so the final top-k can't contain repeats.
+  * Candidate sketches come from a **blocked table** (see
+    :mod:`repro.core.sketch`): 128 / S sketches of S = 16 words share one
+    128-lane row, and stage 1 reads a candidate's whole row and keeps its
+    slot (on a TPU through ``repro.kernels.hamming.gather_sketches``).
+    The ``(n, W)`` table it replaces was stored point-minor on a TPU (the
+    W words on sublanes), so its gather was W element reads per candidate
+    and took ~70 % of a search chunk.  The Hamming distances are the same
+    words, bit for bit.
 
 Fused scan pipeline (the serving hot path): :func:`fused_search_chunk` runs
 the WHOLE per-chunk pipeline — query sketching, a ``lax.scan`` over the
@@ -96,8 +104,9 @@ class HilbertForestIndex(NamedTuple):
 
     Carries no config, so callers of the legacy :func:`search` must re-supply
     the exact build-time ``ForestConfig`` (the footgun the facade removes).
-    Codes here stay UNPACKED (n, d) uint8 for one release of layout
-    compatibility; the facade stores them nibble-packed.
+    Codes here stay UNPACKED (n, d) uint8 and sketches (n, W) for one
+    release of layout compatibility; the facade stores the codes
+    nibble-packed and the sketches as a blocked table.
     """
 
     forest: forest_lib.HilbertForest
@@ -183,22 +192,28 @@ def stage1_tree_merge(
 ):
     """One tree's stage-1: candidates → Hamming filter → merge into top-k2.
 
-    Each step runs under a ``stage1.*`` named scope, which a device trace
-    reports as the op's ``tf_op``.
+    ``sketches_master`` is the blocked sketch table
+    (:func:`repro.core.sketch.block_sketches`); the sketch width W is the
+    query sketches'.  Each step runs under a ``stage1.*`` named scope, which
+    a device trace reports as the op's ``tf_op``.
     """
     with jax.named_scope("stage1.candidates"):
         cand_ids = forest_lib.tree_candidates(
             queries, order, directory, lo, hi, perm, flip,
             bits=bits, key_bits=key_bits, leaf_size=leaf_size, k1=k1,
         )  # (Q, k1)
+    qn, w = qsketches.shape
     with jax.named_scope("stage1.sketch_gather"):
         mpos = master_rank[cand_ids]  # (Q, k1) master positions
-        csk = sketches_master[mpos]  # (Q, k1, Ws)
+        if use_kernels:
+            from repro.kernels import hamming
+
+            csk = hamming.gather_sketches(sketches_master, mpos, w=w)
+        else:
+            csk = sketch.read_blocked(sketches_master, mpos, w)  # (Q, k1, W)
     with jax.named_scope("stage1.hamming"):
         if use_kernels:
-            from repro.kernels.hamming import hamming_rows
-
-            hd = hamming_rows(qsketches, csk, use_kernel=True)  # (Q, k1)
+            hd = hamming.hamming_rows_word_major(qsketches, csk)[:qn, :k1]
         else:
             hd = sketch.hamming_distance(qsketches[:, None, :], csk)
     with jax.named_scope("stage1.merge"):
@@ -356,6 +371,9 @@ def fused_search_chunk(
     use_kernels=False,
 ):
     """ONE dispatch per query chunk: sketch → scan over trees → packed stage 2.
+
+    ``sketches_master`` is the blocked sketch table
+    (:func:`repro.core.sketch.block_sketches`).
 
     The per-tree Python loop becomes a ``lax.scan`` over the stacked forest
     arrays (``orders`` (T, n), ``directories`` (T, n_dir, W), ``perms``/
@@ -529,28 +547,29 @@ def brute_force_topk(queries, points, valid, *, k):
 # old callers get bit-identical results from the same jitted stages.
 # ---------------------------------------------------------------------------
 
-# The legacy container keeps codes unpacked; the facade wants them packed.
-# Cache the packed form per codes array so repeated legacy search() calls
-# don't repack the whole database every time.  Keyed by id(); a weakref
-# finalizer evicts the entry when the source array dies, so the id can
-# never be reused against a stale entry and dropped legacy indexes don't
-# pin database-sized arrays for the process lifetime.
+# The legacy container keeps codes unpacked and sketches (n, W); the facade
+# wants them packed and blocked.  Cache each converted form per source
+# array so repeated legacy search() calls don't convert the whole database
+# every time.  Keyed by (id(), converter); a weakref finalizer evicts the
+# entry when the source array dies, so the id can never be reused against a
+# stale entry and dropped legacy indexes don't pin database-sized arrays
+# for the process lifetime.
 _PACKED_SHIM_CACHE: dict = {}
 
 
-def _packed_codes_cached(codes: jax.Array) -> jax.Array:
+def _shim_cached(arr: jax.Array, convert) -> jax.Array:
     import weakref
 
-    key = id(codes)
+    key = (id(arr), convert)
     hit = _PACKED_SHIM_CACHE.get(key)
-    if hit is None or hit[0]() is not codes:
-        packed = quantize.pack_codes(codes)
+    if hit is None or hit[0]() is not arr:
+        converted = convert(arr)
         try:
-            ref = weakref.ref(codes)
-            weakref.finalize(codes, _PACKED_SHIM_CACHE.pop, key, None)
+            ref = weakref.ref(arr)
+            weakref.finalize(arr, _PACKED_SHIM_CACHE.pop, key, None)
         except TypeError:  # not weakref-able: skip caching
-            return packed
-        _PACKED_SHIM_CACHE[key] = (ref, packed)
+            return converted
+        _PACKED_SHIM_CACHE[key] = (ref, converted)
         hit = _PACKED_SHIM_CACHE[key]
     return hit[1]
 
@@ -573,13 +592,14 @@ def build_index(
         points,
         IndexConfig(forest=forest_cfg, quantizer=quant_cfg, store_points=False),
     )
-    # The facade stores codes nibble-packed; the legacy container documents
-    # the unpacked (n, d) uint8 layout, so unpack (lossless) on the way out.
+    # The facade stores codes nibble-packed and sketches blocked; the legacy
+    # container documents (n, d) uint8 codes and (n, W) sketches, so convert
+    # (losslessly) on the way out.
     return HilbertForestIndex(
         forest=idx.forest,
         quant=idx.quant,
         codes_master=quantize.unpack_codes(idx.codes_master, idx.dim),
-        sketches_master=idx.sketches_master,
+        sketches_master=idx.sketches_view(),
         master_order=idx.master_order,
         master_rank=idx.master_rank,
     )
@@ -616,8 +636,9 @@ def search(
         ),
         forest=index.forest,
         quant=index.quant,
-        codes_master=_packed_codes_cached(index.codes_master),
-        sketches_master=index.sketches_master,
+        codes_master=_shim_cached(index.codes_master, quantize.pack_codes),
+        sketches_master=_shim_cached(index.sketches_master,
+                                     sketch.block_sketches),
         master_order=index.master_order,
         master_rank=index.master_rank,
         points=None,

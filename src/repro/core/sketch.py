@@ -3,9 +3,21 @@
 For d=384 dims the sketch is exactly 384 bits: bit i is ``x_i >= median_i``,
 which is also the MSB of dimension i's 4-bit code — "one bit is shared with
 the sketch".  Hamming distance = XOR + popcount over packed uint32 lanes.
+
+An index keeps its sketches in a **blocked table**: each W-word sketch is
+padded to a slot of S words (the next power of two ≥ W; 16 for W = 12),
+and ``L / S`` consecutive master positions share one row of L = max(128, S)
+lanes, so the table is ``(ceil(n·S / L), L)`` uint32, row-major, with zero
+pad slots and pad words.  Position ``p`` lives in row ``p // (L/S)``, slot
+``p % (L/S)``.  A TPU stores an ``(n, W)`` table point-minor (the W words on
+sublanes, points on lanes), so gathering one sketch from it is W element
+reads; a blocked row is one contiguous 512 B read.  The same bytes either
+way: the point-minor table pads W = 12 sublanes to 16.
 """
 
 from __future__ import annotations
+
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -13,11 +25,81 @@ from jax import lax
 
 from repro.core.quantize import Quantizer
 
-__all__ = ["sketch_words", "make_sketches", "sketches_from_codes", "hamming_distance"]
+__all__ = [
+    "sketch_words",
+    "make_sketches",
+    "sketches_from_codes",
+    "hamming_distance",
+    "slot_words",
+    "table_lanes",
+    "block_sketches",
+    "unblock_sketches",
+    "row_and_slot",
+    "gather_rows",
+    "read_blocked",
+]
 
 
 def sketch_words(d: int) -> int:
     return -(-d // 32)
+
+
+def slot_words(w: int) -> int:
+    """Words per slot of the blocked table: the next power of two ≥ w."""
+    return 1 << max(w - 1, 0).bit_length()
+
+
+def table_lanes(w: int) -> int:
+    """Lanes per row of the blocked table of w-word sketches."""
+    return max(128, slot_words(w))
+
+
+def block_sketches(sketches: jax.Array) -> jax.Array:
+    """(n, W) sketches -> the blocked ``(ceil(n·S/L), L)`` table."""
+    n, w = sketches.shape
+    s, lanes = slot_words(w), table_lanes(w)
+    per_row = lanes // s
+    rows = -(-n // per_row)
+    padded = jnp.pad(sketches, ((0, rows * per_row - n), (0, s - w)))
+    return padded.reshape(rows, lanes)
+
+
+def unblock_sketches(table: jax.Array, n: int, w: int) -> jax.Array:
+    """Inverse of :func:`block_sketches`: the first n sketches, (n, w)."""
+    s = slot_words(w)
+    return table.reshape(-1, s)[:n, :w]
+
+
+def row_and_slot(table: jax.Array, pos: jax.Array, w: int
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The table row that holds each master position of ``pos``, and the
+    position's slot in that row."""
+    per_row = table.shape[1] // slot_words(w)
+    return pos // per_row, pos % per_row
+
+
+def gather_rows(table: jax.Array, pos: jax.Array, w: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The whole table row (..., L) that holds each master position of
+    ``pos`` (...,), and the position's slot in it (...,)."""
+    row, slot = row_and_slot(table, pos, w)
+    return table[row], slot
+
+
+def read_blocked(table: jax.Array, pos: jax.Array, w: int) -> jax.Array:
+    """Sketches at master positions ``pos`` (...,) -> (..., w), in XLA.
+
+    Gathers each position's whole row, then keeps its slot with a masked
+    OR over the row's slots (the other slots read as zero), so no gather
+    indexes below a row.  ``repro.kernels.hamming.gather_sketches`` is the
+    Pallas form for a TPU.
+    """
+    s = slot_words(w)
+    rows, slots = gather_rows(table, pos, w)
+    rows = rows.reshape(pos.shape + (-1, s))
+    hit = slots[..., None] == jnp.arange(rows.shape[-2], dtype=pos.dtype)
+    picked = jnp.where(hit[..., None], rows, jnp.uint32(0))
+    return jnp.bitwise_or.reduce(picked, axis=-2)[..., :w]
 
 
 def pack_bits(bits: jax.Array) -> jax.Array:

@@ -165,7 +165,9 @@ class HilbertIndex:
     forest: forest_lib.HilbertForest
     quant: quantize.Quantizer
     codes_master: jax.Array  # (n, ceil(d/8)) uint32, nibble-PACKED, master order
-    sketches_master: jax.Array  # (n, Ws) uint32, master-order layout
+    # Blocked (ceil(n·S/128), 128) uint32 table, master order: each sketch
+    # in an S-word slot, 128/S per row (repro.core.sketch.block_sketches).
+    sketches_master: jax.Array
     master_order: jax.Array  # (n,) int32: position -> point id
     master_rank: jax.Array  # (n,) int32: point id -> position
     points: Optional[jax.Array] = None  # (n, d) fp32 iff config.store_points
@@ -200,13 +202,21 @@ class HilbertIndex:
         # grid keeps the true dimensionality.
         return self.quant.boundaries.shape[0]
 
+    def sketches_view(self) -> jax.Array:
+        """The sketches as (n, W) uint32 rows in master order, unblocked
+        from the resident table (for graph construction and persistence)."""
+        return sketch.unblock_sketches(
+            self.sketches_master, self.n_points, sketch.sketch_words(self.dim)
+        )
+
     def memory_report(self) -> Dict[str, int]:
         """Bytes by component: the paper's RAM-budget model plus actuals.
 
         The model fields (``quantized_bytes``/``combined_stage2_bytes``/…)
         come from :func:`repro.core.search.paper_memory_model` — the single
         shared accounting.  Since codes are RESIDENT nibble-packed, the
-        model's ``quantized_bytes`` equals the actual ``codes_bytes``.
+        model's ``quantized_bytes`` equals the actual ``codes_bytes``, and
+        ``sketch_bytes`` counts the blocked sketch table's actual bytes.
         ``codes_bytes``/``order_bytes``/``quant_bytes`` are the arrays
         actually resident, and ``resident_bytes``/``total_bytes`` sum every
         pytree leaf so segment lists and serving deployments can budget
@@ -415,8 +425,8 @@ class HilbertIndex:
                 "index was built with IndexConfig(store_points=False)"
             )
         # Sketches in point-id order, recovered from the master-order copy:
-        # sketches_master[master_rank[i]] is point i's sketch.
-        sketches_ids = self.sketches_master[self.master_rank]
+        # row master_rank[i] of the (n, W) view is point i's sketch.
+        sketches_ids = self.sketches_view()[self.master_rank]
         fcfg = self.config.forest
         return knn_graph_lib.knn_graph_from_sketches(
             self.points, sketches_ids, params,
@@ -437,7 +447,8 @@ class HilbertIndex:
             "quant.boundaries": self.quant.boundaries,
             "quant.centroids": self.quant.centroids,
             "codes_master": self.codes_master,
-            "sketches_master": self.sketches_master,
+            # On disk the sketches stay (n, W); load blocks them again.
+            "sketches_master": self.sketches_view(),
             "master_order": self.master_order,
             "master_rank": self.master_rank,
         }
@@ -582,7 +593,7 @@ def _load_index_bundle_step(
             centroids=arrays["quant.centroids"],
         ),
         codes_master=arrays["codes_master"],
-        sketches_master=arrays["sketches_master"],
+        sketches_master=sketch.block_sketches(arrays["sketches_master"]),
         master_order=arrays["master_order"],
         master_rank=arrays["master_rank"],
         points=arrays.get("points"),
@@ -663,7 +674,7 @@ def build_with_timings(
         # Resident layout is nibble-packed (paper: 0.5 B/dim); pack AFTER
         # the master reorder so window reads stay contiguous.
         codes_master=quantize.pack_codes(codes[master_order]),
-        sketches_master=sketches[master_order],
+        sketches_master=sketch.block_sketches(sketches[master_order]),
         master_order=master_order,
         master_rank=master_rank,
         points=points if config.store_points else None,

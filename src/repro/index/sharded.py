@@ -122,7 +122,7 @@ class ShardStack(NamedTuple):
     directories: jax.Array   # (S, T, n_dir, W) uint32 rank directories
     lo: jax.Array            # (S, d) float32 per-shard curve bounds
     hi: jax.Array            # (S, d) float32
-    sketches: jax.Array      # (S, n_pad, Ws) uint32, master-order layout
+    sketches: jax.Array      # (S, R, 128) uint32 blocked tables, master order
     codes: jax.Array         # (S, n_pad, Wc) uint32, nibble-packed, master
     master_order: jax.Array  # (S, n_pad) int32: position -> local row
     master_rank: jax.Array   # (S, n_pad) int32: local row -> position

@@ -17,6 +17,21 @@ The row-wise kernel (stage 1 of Algorithm 1) takes its candidates
 word-major, ``(Q, W, K)``: candidates on the 128 lanes, the W sketch words
 on sublanes.  Laid out ``(Q, K, W)`` a W=12 word row would pad to 128 lanes
 and a (BQ, 1420, 12) block would need ~93 MB of VMEM.
+
+Stage 1 reads those candidates from the index's blocked sketch table
+(``repro.core.sketch``): each sketch sits in an S-word slot (S = 16 for
+W = 12) and 128 / S of them share a 128-lane row.  The table used to be
+``(n, W)``, which a TPU stores point-minor (words on sublanes, points on
+lanes), so XLA gathered each candidate as W element reads — about 21 ns a
+candidate on a v5e, 70 % of a search chunk.  A blocked row is one
+contiguous read.  :func:`sketch_table_kernel` copies a table of up to
+64 MiB into VMEM once per call and reads each candidate's row there with
+one vector load (about 2.3 ns a candidate); a larger table stays in HBM,
+where XLA gathers the rows (about 11 ns a row) and
+:func:`sketch_slots_kernel` keeps each candidate's slot.  Both lay the
+slots out word-major for the row kernel: per query they transpose the
+``(BK, 128)`` row block on the XLU (candidates onto lanes), so no
+relayout of the wide rows is left to XLA.
 """
 
 from __future__ import annotations
@@ -25,7 +40,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.platform import pallas_call
 
@@ -116,3 +133,124 @@ def hamming_rows_kernel(
         out_specs=pl.BlockSpec((bq, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, k), jnp.int32),
     )(queries, candidates)
+
+
+def _pick_slots(rows, slot, s: int, w: int):
+    """(BK, L) rows and their (1, BK) slots -> (w, BK) sketches, word-major:
+    the row block is transposed on the XLU (lanes onto sublanes), then each
+    candidate's S words are selected by its slot."""
+    words = rows.T                             # (L, BK)
+    picked = words[0:s]
+    for j in range(1, words.shape[0] // s):
+        picked = jnp.where(slot == j, words[j * s:(j + 1) * s], picked)
+    return picked[:w]
+
+
+def _sketch_slots_kernel(slot_ref, rows_ref, out_ref, *, s):
+    bq, w, _ = out_ref.shape
+    for i in range(bq):
+        out_ref[i] = _pick_slots(rows_ref[i], slot_ref[pl.ds(i, 1), :], s, w)
+
+
+@functools.partial(jax.jit, static_argnames=("w", "s", "bq", "bk"))
+def sketch_slots_kernel(
+    slots: jax.Array,   # (Q, K) int32 slot of each candidate in its row
+    rows: jax.Array,    # (Q, K, L) uint32 blocked-table rows, one per candidate
+    *,
+    w: int,
+    s: int,
+    bq: int = ROWS_BQ,
+    bk: int = ROWS_BK,
+) -> jax.Array:
+    """Each candidate's ``w``-word sketch from its gathered row, word-major
+    ``(Q, w, K)`` for :func:`hamming_rows_kernel`.  ``s`` is the slot width;
+    Q must be a multiple of bq and K of bk (ops.py pads)."""
+    qn, k, lanes = rows.shape
+    return pallas_call(
+        functools.partial(_sketch_slots_kernel, s=s),
+        name="sketch_slots",
+        grid=(qn // bq, k // bk),
+        in_specs=[
+            pl.BlockSpec((bq, bk), lambda i, j: (i, j)),
+            pl.BlockSpec((bq, bk, lanes), lambda i, j: (i, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((bq, w, bk), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((qn, w, k), rows.dtype),
+    )(slots, rows)
+
+
+# Rows fetched per iteration of the table kernel's row loop.
+_FETCH_UNROLL = 8
+
+
+def _sketch_table_kernel(row_ref, slot_ref, table_hbm, out_ref, table, buf,
+                         sem, *, s):
+    bq, w, bk = out_ref.shape
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        copy = pltpu.make_async_copy(table_hbm, table, sem.at[0])
+        copy.start()
+        copy.wait()
+
+    def fetch(r, carry):
+        for u in range(_FETCH_UNROLL):
+            i = r * _FETCH_UNROLL + u
+            buf[pl.ds(i, 1), :] = table[pl.ds(row_ref[i], 1), :]
+        return carry
+
+    lax.fori_loop(0, bq * bk // _FETCH_UNROLL, fetch, 0)
+    for i in range(bq):
+        out_ref[i] = _pick_slots(buf[pl.ds(i * bk, bk), :],
+                                 slot_ref[pl.ds(i, 1), :], s, w)
+
+
+@functools.partial(jax.jit, static_argnames=("w", "s", "bq", "bk"))
+def sketch_table_kernel(
+    rows: jax.Array,    # (Q, K) int32 table row of each candidate
+    slots: jax.Array,   # (Q, K) int32 slot of each candidate in its row
+    table: jax.Array,   # (R, L) uint32 blocked sketch table
+    *,
+    w: int,
+    s: int,
+    bq: int = ROWS_BQ,
+    bk: int = ROWS_BK,
+) -> jax.Array:
+    """Candidate sketches read from a blocked table held in VMEM, word-major
+    ``(Q, w, K)`` for :func:`hamming_rows_kernel`.
+
+    The first grid step copies the whole table into VMEM (one contiguous
+    DMA); every step then reads its ``bq * bk`` candidates' rows with one
+    dynamic-sublane vector load each, their row indices from SMEM, and
+    keeps each candidate's slot.  For tables that fit VMEM next to the
+    blocks (ops.py decides).  Q must be a multiple of bq and K of bk.
+    """
+    qn, k = rows.shape
+    n_rows, lanes = table.shape
+    nk = k // bk
+    # The row indices reach SMEM one step's block at a time, query-major.
+    flat = rows.reshape(qn // bq, bq, nk, bk).transpose(0, 2, 1, 3)
+    vmem = table.nbytes + bq * bk * lanes * 4
+    return pallas_call(
+        functools.partial(_sketch_table_kernel, s=s),
+        name="sketch_table",
+        grid=(qn // bq * nk,),
+        in_specs=[
+            pl.BlockSpec((bq * bk,), lambda t: (t,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((bq, bk), lambda t: (t // nk, t % nk)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((bq, w, bk), lambda t: (t // nk, 0, t % nk)),
+        out_shape=jax.ShapeDtypeStruct((qn, w, k), table.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((n_rows, lanes), table.dtype),
+            pltpu.VMEM((bq * bk, lanes), table.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        # Sequential steps: the table copied at step 0 serves every step.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem + (32 << 20),
+        ),
+    )(flat.reshape(-1), slots, table)

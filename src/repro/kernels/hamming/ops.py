@@ -13,10 +13,18 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import sketch
 from repro.kernels.hamming.kernel import (BC, BQ, ROWS_BK, ROWS_BQ,
                                           hamming_matrix_kernel,
-                                          hamming_rows_kernel)
+                                          hamming_rows_kernel,
+                                          sketch_slots_kernel,
+                                          sketch_table_kernel)
 from repro.kernels.hamming.ref import hamming_matrix_ref
+
+
+# Largest blocked sketch table read from VMEM: a 2^20-row index's table
+# (64 MiB), within a v5e's 128 MiB of VMEM beside the kernel's blocks.
+VMEM_TABLE_BYTES = 64 << 20
 
 
 def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
@@ -66,9 +74,51 @@ def hamming_rows(
         x = jnp.bitwise_xor(queries[:, None, :], candidates)
         return jnp.sum(lax.population_count(x).astype(jnp.int32), axis=-1)
     qn, k = candidates.shape[:2]
-    bk = min(ROWS_BK, -(-k // 128) * 128)
     # Word-major candidates (Q, W, K): K on lanes (see kernel.py).
+    cp = _pad_to(_pad_to(jnp.swapaxes(candidates, 1, 2), ROWS_BQ, 0),
+                 _rows_bk(k), 2)
+    return hamming_rows_word_major(queries, cp)[:qn, :k]
+
+
+def _rows_bk(k: int) -> int:
+    """The row kernels' candidate tile for K candidates per query."""
+    return min(ROWS_BK, -(-k // 128) * 128)
+
+
+@jax.jit
+def hamming_rows_word_major(queries: jax.Array, candidates: jax.Array
+                            ) -> jax.Array:
+    """(Q, W) vs word-major (Q', W, K') candidates, as :func:`gather_sketches`
+    returns them (Q' ≥ Q and K' padded to the row tiles) -> (Q', K') int32,
+    always through the Pallas kernel."""
     qp = _pad_to(queries, ROWS_BQ, 0)[:, :, None]
-    cp = _pad_to(_pad_to(jnp.swapaxes(candidates, 1, 2), ROWS_BQ, 0), bk, 2)
-    out = hamming_rows_kernel(qp, cp, bk=bk)
-    return out[:qn, :k]
+    return hamming_rows_kernel(qp, candidates, bk=_rows_bk(candidates.shape[2]))
+
+
+@functools.partial(jax.jit, static_argnames=("w",))
+def gather_sketches(table: jax.Array, pos: jax.Array, *, w: int) -> jax.Array:
+    """Candidate sketches from a blocked sketch table, word-major.
+
+    Args:
+      table: (R, L) uint32 blocked table (``repro.core.sketch``).
+      pos: (Q, K) int32 master positions.
+      w: words per sketch.
+
+    Returns:
+      (Q', w, K') uint32 with Q' and K' padded to the row kernels' tiles;
+      padded entries hold position 0's sketch.
+
+    A table of up to ``VMEM_TABLE_BYTES`` is copied whole into VMEM, and
+    the candidates' rows are read there (``sketch_table_kernel``).  A
+    larger one stays in HBM: XLA gathers one whole L-lane row per
+    candidate and ``sketch_slots_kernel`` keeps its slot.  On a v5e at
+    Table-1 shapes the first costs 2.0 ms a tree, the second 9.0.
+    """
+    bk = _rows_bk(pos.shape[1])
+    pos = _pad_to(_pad_to(pos, ROWS_BQ, 0), bk, 1)
+    s = sketch.slot_words(w)
+    if table.nbytes <= VMEM_TABLE_BYTES:
+        rows, slots = sketch.row_and_slot(table, pos, w)
+        return sketch_table_kernel(rows, slots, table, w=w, s=s, bk=bk)
+    rows, slots = sketch.gather_rows(table, pos, w)
+    return sketch_slots_kernel(slots, rows, w=w, s=s, bk=bk)

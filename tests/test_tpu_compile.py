@@ -21,6 +21,7 @@ from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -41,8 +42,10 @@ ROW1 = pubmed23.TABLE1[0]   # k1=1420, k2=370, h=2, k=30
 N = 1 << 20
 QUERY_CHUNK = IndexConfig().query_chunk
 HBM_V5E = 16 << 30
-# memory_report()["resident_bytes"] of the 2^20-row pubmed23 index.
-RESIDENT_2_20 = 2_636_060_672
+# memory_report()["resident_bytes"] of the 2^20-row pubmed23 index.  Its
+# sketch table is blocked: 12-word sketches in 16-word slots, 8 to a row.
+RESIDENT_2_20 = 2_652_837_888
+SKETCH_ROWS = N * 16 // 128
 
 
 @pytest.fixture(scope="module")
@@ -134,7 +137,7 @@ def _lower_search_chunk(sharding, use_kernels, *, n=N, trees=None,
     )
     return jax.jit(chunk).lower(
         _spec((q, D), jnp.float32, s), forest,
-        _spec((n,), jnp.int32, s), _spec((n, W_SKETCH), jnp.uint32, s),
+        _spec((n,), jnp.int32, s), _spec((n * 16 // 128, 128), jnp.uint32, s),
         _spec((n, W_CODES), jnp.uint32, s), _spec((n,), jnp.int32, s), quant,
     )
 
@@ -149,10 +152,92 @@ def _compile_search_chunk(sharding, use_kernels):
     return compiled.as_text(), temp
 
 
-def test_fused_search_chunk_compiles_for_v5e(one_chip):
-    text, temp = _compile_search_chunk(one_chip, True)
+@pytest.fixture(scope="module")
+def pallas_chunk(one_chip):
+    """The fused chunk's Pallas route at Table-1 shapes, compiled once."""
+    return _compile_search_chunk(one_chip, True)
+
+
+def test_fused_search_chunk_compiles_for_v5e(pallas_chunk):
+    text, temp = pallas_chunk
     assert "tpu_custom_call" in text
     assert temp < 2 << 30, temp
+
+
+def _hlo_shape(text: str, name: str) -> str:
+    """The result type of instruction ``%name`` in optimized HLO text."""
+    m = re.search(r"%" + re.escape(name) + r" = (\S+) ", text)
+    assert m, name
+    return m.group(1)
+
+
+def _elements(shape: str) -> int:
+    dims = re.match(r"\w+\[([\d,]*)\]", shape).group(1)
+    return int(np.prod([int(x) for x in dims.split(",") if x]))
+
+
+def _kernel_signature(text: str, name: str):
+    """Result type and operand types (with layouts) of Mosaic kernel
+    ``name`` in optimized HLO text."""
+    m = re.search(
+        r"%" + name + r"[\w.]* = (\w+)\[.*?custom_call_target="
+        r'"tpu_custom_call", operand_layout_constraints='
+        r"\{((?:[^{}]|\{[^{}]*\})*)\}", text)
+    assert m, name
+    return m.group(1), re.findall(r"(\w+\[[\d,]*\]\{[\d,]*)\}", m.group(2))
+
+
+def _assert_no_point_minor_read(text: str, wide: int):
+    """No gather reads a sketch's words one by one, no copy relays a
+    tensor as wide as one 128-lane row per candidate, and the Hamming
+    kernel still returns s32 from two u32 operands."""
+    gathers = re.findall(
+        r"gather\(%([\w.\-]+), [^)]*\).*?slice_sizes=\{([\d,]+)\}", text)
+    assert not [g for g in gathers if g[1] == f"1,{W_SKETCH}"]
+    copies = re.findall(r"= (\S+) copy\(", text)
+    assert not [c for c in copies if _elements(c) >= wide], copies
+    result, operands = _kernel_signature(text, "hamming_rows")
+    assert result == "s32"
+    assert [op.split("[")[0] for op in operands] == ["u32", "u32"], operands
+    return gathers
+
+
+def test_stage1_reads_whole_sketch_rows(pallas_chunk):
+    """At Table-1 shapes the blocked table (64 MiB) is read from VMEM by
+    the ``sketch_table`` kernel, as the row-major ``{1,0}`` table the index
+    holds, and nothing reads a candidate's words one by one."""
+    text, _ = pallas_chunk
+    _assert_no_point_minor_read(text, QUERY_CHUNK * ROW1.k1 * 128)
+    result, operands = _kernel_signature(text, "sketch_table")
+    assert result == "u32"
+    assert operands[-1] == f"u32[{SKETCH_ROWS},128]{{1,0", operands
+
+
+def test_stage1_gathers_whole_rows_past_vmem(one_chip):
+    """A table larger than VMEM takes the HBM route: XLA gathers one whole
+    128-lane row of the row-major table per candidate, and the
+    ``sketch_slots`` kernel reads the gathered rows with no relayout."""
+    fcfg, s, q, n = pubmed23.FOREST, one_chip, QUERY_CHUNK, 2 * N
+    n_dir = -(-n // fcfg.leaf_size)
+    key_w = -(-fcfg.key_bits // 32)
+    text = jax.jit(lambda *a: search_lib.stage1_tree_merge(
+        *a, bits=fcfg.bits, key_bits=fcfg.key_bits,
+        leaf_size=fcfg.leaf_size, k1=ROW1.k1, k2=ROW1.k2, use_kernels=True,
+    )).lower(
+        _spec((q, D), jnp.float32, s), _spec((q, W_SKETCH), jnp.uint32, s),
+        _spec((q, ROW1.k2), jnp.int32, s), _spec((q, ROW1.k2), jnp.int32, s),
+        _spec((n,), jnp.int32, s), _spec((n_dir, key_w), jnp.uint32, s),
+        _spec((D,), jnp.float32, s), _spec((D,), jnp.float32, s),
+        _spec((D,), jnp.int32, s), _spec((D,), jnp.bool_, s),
+        _spec((n,), jnp.int32, s), _spec((n * 16 // 128, 128), jnp.uint32, s),
+    ).compile().as_text()
+    gathers = _assert_no_point_minor_read(text, q * ROW1.k1 * 128)
+    rows = [op for op, sizes in gathers if sizes == "1,128"]
+    assert rows
+    for op in rows:
+        assert _hlo_shape(text, op).startswith(
+            f"u32[{n * 16 // 128},128]{{1,0"), _hlo_shape(text, op)
+    assert _kernel_signature(text, "sketch_slots")[0] == "u32"
 
 
 def test_fused_search_chunk_xla_route_fits_v5e(one_chip):
