@@ -24,13 +24,14 @@ Implementation notes vs the pseudocode:
     gathers over the sorted copies).
   * Duplicates (same point from several trees / overlapping windows) are
     deduped during the merge so the final top-k can't contain repeats.
-  * Candidate sketches come from a **blocked table** (see
+  * On a TPU candidate sketches come from a **blocked table** (see
     :mod:`repro.core.sketch`): 128 / S sketches of S = 16 words share one
     128-lane row, and stage 1 reads a candidate's whole row and keeps its
-    slot (on a TPU through ``repro.kernels.hamming.gather_sketches``).
-    The ``(n, W)`` table it replaces was stored point-minor on a TPU (the
-    W words on sublanes), so its gather was W element reads per candidate
-    and took ~70 % of a search chunk.  The Hamming distances are the same
+    slot (through ``repro.kernels.hamming.gather_sketches``).  An ``(n, W)``
+    table is stored point-minor on a TPU (the W words on sublanes), so its
+    gather was W element reads per candidate and took ~70 % of a search
+    chunk.  Off a TPU the index keeps the ``(n, W)`` rows, whose gather is
+    already one contiguous read each.  The Hamming distances are the same
     words, bit for bit.
 
 Fused scan pipeline (the serving hot path): :func:`fused_search_chunk` runs
@@ -106,7 +107,7 @@ class HilbertForestIndex(NamedTuple):
     the exact build-time ``ForestConfig`` (the footgun the facade removes).
     Codes here stay UNPACKED (n, d) uint8 and sketches (n, W) for one
     release of layout compatibility; the facade stores the codes
-    nibble-packed and the sketches as a blocked table.
+    nibble-packed and, on a TPU, the sketches as a blocked table.
     """
 
     forest: forest_lib.HilbertForest
@@ -192,10 +193,10 @@ def stage1_tree_merge(
 ):
     """One tree's stage-1: candidates → Hamming filter → merge into top-k2.
 
-    ``sketches_master`` is the blocked sketch table
-    (:func:`repro.core.sketch.block_sketches`); the sketch width W is the
-    query sketches'.  Each step runs under a ``stage1.*`` named scope, which
-    a device trace reports as the op's ``tf_op``.
+    ``sketches_master`` is the index's resident sketch table, blocked or
+    (n, W) (:func:`repro.core.sketch.resident_table`); the sketch width W
+    is the query sketches'.  Each step runs under a ``stage1.*`` named
+    scope, which a device trace reports as the op's ``tf_op``.
     """
     with jax.named_scope("stage1.candidates"):
         cand_ids = forest_lib.tree_candidates(
@@ -210,7 +211,7 @@ def stage1_tree_merge(
 
             csk = hamming.gather_sketches(sketches_master, mpos, w=w)
         else:
-            csk = sketch.read_blocked(sketches_master, mpos, w)  # (Q, k1, W)
+            csk = sketch.read_sketches(sketches_master, mpos, w)  # (Q, k1, W)
     with jax.named_scope("stage1.hamming"):
         if use_kernels:
             hd = hamming.hamming_rows_word_major(qsketches, csk)[:qn, :k1]
@@ -372,8 +373,8 @@ def fused_search_chunk(
 ):
     """ONE dispatch per query chunk: sketch → scan over trees → packed stage 2.
 
-    ``sketches_master`` is the blocked sketch table
-    (:func:`repro.core.sketch.block_sketches`).
+    ``sketches_master`` is the index's resident sketch table
+    (:func:`repro.core.sketch.resident_table`).
 
     The per-tree Python loop becomes a ``lax.scan`` over the stacked forest
     arrays (``orders`` (T, n), ``directories`` (T, n_dir, W), ``perms``/
@@ -548,12 +549,12 @@ def brute_force_topk(queries, points, valid, *, k):
 # ---------------------------------------------------------------------------
 
 # The legacy container keeps codes unpacked and sketches (n, W); the facade
-# wants them packed and blocked.  Cache each converted form per source
-# array so repeated legacy search() calls don't convert the whole database
-# every time.  Keyed by (id(), converter); a weakref finalizer evicts the
-# entry when the source array dies, so the id can never be reused against a
-# stale entry and dropped legacy indexes don't pin database-sized arrays
-# for the process lifetime.
+# wants them packed and laid out as its resident table.  Cache each
+# converted form per source array so repeated legacy search() calls don't
+# convert the whole database every time.  Keyed by (id(), converter); a
+# weakref finalizer evicts the entry when the source array dies, so the id
+# can never be reused against a stale entry and dropped legacy indexes don't
+# pin database-sized arrays for the process lifetime.
 _PACKED_SHIM_CACHE: dict = {}
 
 
@@ -564,6 +565,8 @@ def _shim_cached(arr: jax.Array, convert) -> jax.Array:
     hit = _PACKED_SHIM_CACHE.get(key)
     if hit is None or hit[0]() is not arr:
         converted = convert(arr)
+        if converted is arr:  # nothing to convert, and nothing to pin
+            return arr
         try:
             ref = weakref.ref(arr)
             weakref.finalize(arr, _PACKED_SHIM_CACHE.pop, key, None)
@@ -592,9 +595,9 @@ def build_index(
         points,
         IndexConfig(forest=forest_cfg, quantizer=quant_cfg, store_points=False),
     )
-    # The facade stores codes nibble-packed and sketches blocked; the legacy
-    # container documents (n, d) uint8 codes and (n, W) sketches, so convert
-    # (losslessly) on the way out.
+    # The facade stores codes nibble-packed and sketches in its resident
+    # table layout; the legacy container documents (n, d) uint8 codes and
+    # (n, W) sketches, so convert (losslessly) on the way out.
     return HilbertForestIndex(
         forest=idx.forest,
         quant=idx.quant,
@@ -638,7 +641,7 @@ def search(
         quant=index.quant,
         codes_master=_shim_cached(index.codes_master, quantize.pack_codes),
         sketches_master=_shim_cached(index.sketches_master,
-                                     sketch.block_sketches),
+                                     sketch.resident_table),
         master_order=index.master_order,
         master_rank=index.master_rank,
         points=None,

@@ -4,15 +4,20 @@ For d=384 dims the sketch is exactly 384 bits: bit i is ``x_i >= median_i``,
 which is also the MSB of dimension i's 4-bit code — "one bit is shared with
 the sketch".  Hamming distance = XOR + popcount over packed uint32 lanes.
 
-An index keeps its sketches in a **blocked table**: each W-word sketch is
-padded to a slot of S words (the next power of two ≥ W; 16 for W = 12),
-and ``L / S`` consecutive master positions share one row of L = max(128, S)
-lanes, so the table is ``(ceil(n·S / L), L)`` uint32, row-major, with zero
-pad slots and pad words.  Position ``p`` lives in row ``p // (L/S)``, slot
-``p % (L/S)``.  A TPU stores an ``(n, W)`` table point-minor (the W words on
-sublanes, points on lanes), so gathering one sketch from it is W element
-reads; a blocked row is one contiguous 512 B read.  The same bytes either
-way: the point-minor table pads W = 12 sublanes to 16.
+An index on a TPU keeps its sketches in a **blocked table**: each W-word
+sketch is padded to a slot of S words (the next power of two ≥ W; 16 for
+W = 12), and ``L / S`` consecutive master positions share one row of
+L = max(128, S) lanes, so the table is ``(ceil(n·S / L), L)`` uint32,
+row-major, with zero pad slots and pad words.  Position ``p`` lives in row
+``p // (L/S)``, slot ``p % (L/S)``.  A TPU stores an ``(n, W)`` table
+point-minor (the W words on sublanes, points on lanes), so gathering one
+sketch from it is W element reads; a blocked row is one contiguous 512 B
+read.  The same device bytes either way: the point-minor table pads W = 12
+sublanes to 16.  Elsewhere an ``(n, W)`` row is already one contiguous read
+and the slots would only pad it, so an index off a TPU keeps the ``(n, W)``
+rows (:func:`resident_table`).  Readers tell the two apart by shape: a
+table is blocked iff its rows are not W words wide (for W = 128 the two
+layouts are the same array).
 """
 
 from __future__ import annotations
@@ -33,10 +38,13 @@ __all__ = [
     "slot_words",
     "table_lanes",
     "block_sketches",
+    "resident_table",
+    "is_blocked",
     "unblock_sketches",
     "row_and_slot",
     "gather_rows",
     "read_blocked",
+    "read_sketches",
 ]
 
 
@@ -64,8 +72,24 @@ def block_sketches(sketches: jax.Array) -> jax.Array:
     return padded.reshape(rows, lanes)
 
 
+def resident_table(sketches: jax.Array) -> jax.Array:
+    """The sketch table an index keeps for its (n, W) master-order rows:
+    blocked where the rows live on a TPU, else the rows themselves."""
+    if all(d.platform == "tpu" for d in sketches.devices()):
+        return block_sketches(sketches)
+    return sketches
+
+
+def is_blocked(table: jax.Array, w: int) -> bool:
+    """Whether ``table`` is a blocked table of w-word sketches, by shape."""
+    return table.shape[1] != w
+
+
 def unblock_sketches(table: jax.Array, n: int, w: int) -> jax.Array:
-    """Inverse of :func:`block_sketches`: the first n sketches, (n, w)."""
+    """The first n sketches of a resident table, (n, w): the inverse of
+    :func:`block_sketches`, and an (n, W) table as it is."""
+    if not is_blocked(table, w):
+        return table[:n]
     s = slot_words(w)
     return table.reshape(-1, s)[:n, :w]
 
@@ -87,7 +111,8 @@ def gather_rows(table: jax.Array, pos: jax.Array, w: int
 
 
 def read_blocked(table: jax.Array, pos: jax.Array, w: int) -> jax.Array:
-    """Sketches at master positions ``pos`` (...,) -> (..., w), in XLA.
+    """Sketches at master positions ``pos`` (...,) of a blocked table ->
+    (..., w), in XLA.
 
     Gathers each position's whole row, then keeps its slot with a masked
     OR over the row's slots (the other slots read as zero), so no gather
@@ -100,6 +125,14 @@ def read_blocked(table: jax.Array, pos: jax.Array, w: int) -> jax.Array:
     hit = slots[..., None] == jnp.arange(rows.shape[-2], dtype=pos.dtype)
     picked = jnp.where(hit[..., None], rows, jnp.uint32(0))
     return jnp.bitwise_or.reduce(picked, axis=-2)[..., :w]
+
+
+def read_sketches(table: jax.Array, pos: jax.Array, w: int) -> jax.Array:
+    """Sketches at master positions ``pos`` (...,) of a resident table
+    (blocked or (n, W)) -> (..., w), in XLA."""
+    if is_blocked(table, w):
+        return read_blocked(table, pos, w)
+    return table[pos]
 
 
 def pack_bits(bits: jax.Array) -> jax.Array:

@@ -165,8 +165,9 @@ class HilbertIndex:
     forest: forest_lib.HilbertForest
     quant: quantize.Quantizer
     codes_master: jax.Array  # (n, ceil(d/8)) uint32, nibble-PACKED, master order
-    # Blocked (ceil(n·S/128), 128) uint32 table, master order: each sketch
-    # in an S-word slot, 128/S per row (repro.core.sketch.block_sketches).
+    # Master order; on a TPU the blocked (ceil(n·S/128), 128) uint32 table
+    # (each sketch in an S-word slot, 128/S per row), elsewhere (n, W)
+    # uint32 rows (repro.core.sketch.resident_table).
     sketches_master: jax.Array
     master_order: jax.Array  # (n,) int32: position -> point id
     master_rank: jax.Array  # (n,) int32: point id -> position
@@ -203,8 +204,8 @@ class HilbertIndex:
         return self.quant.boundaries.shape[0]
 
     def sketches_view(self) -> jax.Array:
-        """The sketches as (n, W) uint32 rows in master order, unblocked
-        from the resident table (for graph construction and persistence)."""
+        """The sketches as (n, W) uint32 rows in master order, read from
+        the resident table (for graph construction and persistence)."""
         return sketch.unblock_sketches(
             self.sketches_master, self.n_points, sketch.sketch_words(self.dim)
         )
@@ -215,8 +216,10 @@ class HilbertIndex:
         The model fields (``quantized_bytes``/``combined_stage2_bytes``/…)
         come from :func:`repro.core.search.paper_memory_model` — the single
         shared accounting.  Since codes are RESIDENT nibble-packed, the
-        model's ``quantized_bytes`` equals the actual ``codes_bytes``, and
-        ``sketch_bytes`` counts the blocked sketch table's actual bytes.
+        model's ``quantized_bytes`` equals the actual ``codes_bytes``.  The
+        model counts the sketches as the paper does, n·W words;
+        ``sketch_table_bytes`` is the resident table's actual size (on a
+        TPU, blocked: 16-word slots for W = 12).
         ``codes_bytes``/``order_bytes``/``quant_bytes`` are the arrays
         actually resident, and ``resident_bytes``/``total_bytes`` sum every
         pytree leaf so segment lists and serving deployments can budget
@@ -230,11 +233,12 @@ class HilbertIndex:
         rep = search_lib.paper_memory_model(
             self.n_points,
             d,
-            int(np.prod(self.sketches_master.shape)) * 4,
+            self.n_points * sketch.sketch_words(d) * 4,
             self.forest.memory_bytes(),
         )
         rep.update(
             {
+                "sketch_table_bytes": self.sketches_master.nbytes,
                 "points_bytes": 0 if self.points is None else self.n_points * d * 4,
                 "codes_bytes": int(np.prod(self.codes_master.shape)) * 4,  # u32
                 "order_bytes": self.master_order.nbytes + self.master_rank.nbytes,
@@ -447,7 +451,8 @@ class HilbertIndex:
             "quant.boundaries": self.quant.boundaries,
             "quant.centroids": self.quant.centroids,
             "codes_master": self.codes_master,
-            # On disk the sketches stay (n, W); load blocks them again.
+            # On disk the sketches stay (n, W) whatever the device; load
+            # lays them out for its own.
             "sketches_master": self.sketches_view(),
             "master_order": self.master_order,
             "master_rank": self.master_rank,
@@ -593,7 +598,7 @@ def _load_index_bundle_step(
             centroids=arrays["quant.centroids"],
         ),
         codes_master=arrays["codes_master"],
-        sketches_master=sketch.block_sketches(arrays["sketches_master"]),
+        sketches_master=sketch.resident_table(arrays["sketches_master"]),
         master_order=arrays["master_order"],
         master_rank=arrays["master_rank"],
         points=arrays.get("points"),
@@ -674,7 +679,7 @@ def build_with_timings(
         # Resident layout is nibble-packed (paper: 0.5 B/dim); pack AFTER
         # the master reorder so window reads stay contiguous.
         codes_master=quantize.pack_codes(codes[master_order]),
-        sketches_master=sketch.block_sketches(sketches[master_order]),
+        sketches_master=sketch.resident_table(sketches[master_order]),
         master_order=master_order,
         master_rank=master_rank,
         points=points if config.store_points else None,

@@ -74,6 +74,7 @@ from repro.core import distributed as distributed_lib
 from repro.core import forest as forest_lib
 from repro.core import quantize
 from repro.core import search as search_lib
+from repro.core import sketch
 from repro.core.types import SearchParams
 from repro.index.config import IndexConfig
 from repro.obs.dispatch import dispatch_scope
@@ -122,7 +123,7 @@ class ShardStack(NamedTuple):
     directories: jax.Array   # (S, T, n_dir, W) uint32 rank directories
     lo: jax.Array            # (S, d) float32 per-shard curve bounds
     hi: jax.Array            # (S, d) float32
-    sketches: jax.Array      # (S, R, 128) uint32 blocked tables, master order
+    sketches: jax.Array      # (S, n_pad, Ws) uint32 (on TPU blocked), master
     codes: jax.Array         # (S, n_pad, Wc) uint32, nibble-packed, master
     master_order: jax.Array  # (S, n_pad) int32: position -> local row
     master_rank: jax.Array   # (S, n_pad) int32: local row -> position
@@ -276,7 +277,7 @@ class ShardedHilbertIndex:
         rep = search_lib.paper_memory_model(
             self.n_points,
             self.dim,
-            int(self.stack.sketches.nbytes),
+            self.n_points * sketch.sketch_words(self.dim) * 4,
             int(self.stack.orders.nbytes + self.stack.directories.nbytes
                 + self.perms.nbytes + self.flips.nbytes),
         )
@@ -284,6 +285,7 @@ class ShardedHilbertIndex:
             n_shards=s,
             points_bytes=0 if self.points is None else int(self.points.nbytes),
             codes_bytes=int(self.stack.codes.nbytes),
+            sketch_table_bytes=int(self.stack.sketches.nbytes),
             sharded_bytes=sharded,
             replicated_bytes=replicated,
             resident_bytes=sharded + replicated,

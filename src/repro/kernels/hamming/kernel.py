@@ -18,20 +18,20 @@ word-major, ``(Q, W, K)``: candidates on the 128 lanes, the W sketch words
 on sublanes.  Laid out ``(Q, K, W)`` a W=12 word row would pad to 128 lanes
 and a (BQ, 1420, 12) block would need ~93 MB of VMEM.
 
-Stage 1 reads those candidates from the index's blocked sketch table
-(``repro.core.sketch``): each sketch sits in an S-word slot (S = 16 for
-W = 12) and 128 / S of them share a 128-lane row.  The table used to be
-``(n, W)``, which a TPU stores point-minor (words on sublanes, points on
-lanes), so XLA gathered each candidate as W element reads — about 21 ns a
-candidate on a v5e, 70 % of a search chunk.  A blocked row is one
-contiguous read.  :func:`sketch_table_kernel` copies a table of up to
-64 MiB into VMEM once per call and reads each candidate's row there with
-one vector load (about 2.3 ns a candidate); a larger table stays in HBM,
-where XLA gathers the rows (about 11 ns a row) and
+On a TPU stage 1 reads those candidates from the index's blocked sketch
+table (``repro.core.sketch``): each sketch sits in an S-word slot (S = 16
+for W = 12) and 128 / S of them share a 128-lane row.  An ``(n, W)`` table
+is stored point-minor on a TPU (words on sublanes, points on lanes), so
+XLA gathered each candidate as W element reads; a blocked row is one
+contiguous read.  :func:`sketch_table_kernel` copies a table into VMEM
+once per call and reads each candidate's row there with one vector load;
+where the table does not fit VMEM, or too few candidates are read to pay
+for the copy (``ops.table_in_vmem``), XLA gathers the rows from HBM and
 :func:`sketch_slots_kernel` keeps each candidate's slot.  Both lay the
 slots out word-major for the row kernel: per query they transpose the
 ``(BK, 128)`` row block on the XLU (candidates onto lanes), so no
-relayout of the wide rows is left to XLA.
+relayout of the wide rows is left to XLA, whose own slot select relays
+the gathered ``(Q·K, 128)`` rows out.  Times per route on a v5e: PERF.md.
 """
 
 from __future__ import annotations
@@ -155,7 +155,7 @@ def _sketch_slots_kernel(slot_ref, rows_ref, out_ref, *, s):
 @functools.partial(jax.jit, static_argnames=("w", "s", "bq", "bk"))
 def sketch_slots_kernel(
     slots: jax.Array,   # (Q, K) int32 slot of each candidate in its row
-    rows: jax.Array,    # (Q, K, L) uint32 blocked-table rows, one per candidate
+    rows: jax.Array,    # (Q, K, L) uint32 blocked-table row per candidate
     *,
     w: int,
     s: int,
@@ -181,6 +181,15 @@ def sketch_slots_kernel(
 
 # Rows fetched per iteration of the table kernel's row loop.
 _FETCH_UNROLL = 8
+# VMEM the table kernel leaves to Mosaic beyond its table and row buffer.
+_TABLE_VMEM_HEADROOM = 32 << 20
+
+
+def sketch_table_vmem_bytes(table_bytes: int, lanes: int,
+                            bq: int = ROWS_BQ, bk: int = ROWS_BK) -> int:
+    """VMEM :func:`sketch_table_kernel` asks for with a table of
+    ``table_bytes``: the table, one step's rows, and headroom."""
+    return table_bytes + bq * bk * lanes * 4 + _TABLE_VMEM_HEADROOM
 
 
 def _sketch_table_kernel(row_ref, slot_ref, table_hbm, out_ref, table, buf,
@@ -230,7 +239,6 @@ def sketch_table_kernel(
     nk = k // bk
     # The row indices reach SMEM one step's block at a time, query-major.
     flat = rows.reshape(qn // bq, bq, nk, bk).transpose(0, 2, 1, 3)
-    vmem = table.nbytes + bq * bk * lanes * 4
     return pallas_call(
         functools.partial(_sketch_table_kernel, s=s),
         name="sketch_table",
@@ -251,6 +259,7 @@ def sketch_table_kernel(
         # Sequential steps: the table copied at step 0 serves every step.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=vmem + (32 << 20),
+            vmem_limit_bytes=sketch_table_vmem_bytes(table.nbytes, lanes,
+                                                     bq, bk),
         ),
     )(flat.reshape(-1), slots, table)

@@ -12,19 +12,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import sketch
 from repro.kernels.hamming.kernel import (BC, BQ, ROWS_BK, ROWS_BQ,
                                           hamming_matrix_kernel,
                                           hamming_rows_kernel,
                                           sketch_slots_kernel,
-                                          sketch_table_kernel)
+                                          sketch_table_kernel,
+                                          sketch_table_vmem_bytes)
 from repro.kernels.hamming.ref import hamming_matrix_ref
 
 
-# Largest blocked sketch table read from VMEM: a 2^20-row index's table
-# (64 MiB), within a v5e's 128 MiB of VMEM beside the kernel's blocks.
-VMEM_TABLE_BYTES = 64 << 20
+# Time a candidate row read from HBM (XLA's row gather and the slot
+# select) takes beyond one read from a table held in VMEM, on a v5e.
+HBM_ROW_EXTRA_S = 8.8e-9
 
 
 def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
@@ -92,15 +94,33 @@ def hamming_rows_word_major(queries: jax.Array, candidates: jax.Array
     returns them (Q' ≥ Q and K' padded to the row tiles) -> (Q', K') int32,
     always through the Pallas kernel."""
     qp = _pad_to(queries, ROWS_BQ, 0)[:, :, None]
-    return hamming_rows_kernel(qp, candidates, bk=_rows_bk(candidates.shape[2]))
+    return hamming_rows_kernel(qp, candidates,
+                               bk=_rows_bk(candidates.shape[2]))
+
+
+def table_in_vmem(table_bytes: int, reads: int, lanes: int) -> bool:
+    """Whether stage 1 reads ``reads`` candidate rows from a blocked table
+    of ``table_bytes`` copied into VMEM, rather than gathering them from
+    HBM: the table fits the chip's VMEM beside the kernel's blocks, and
+    copying it in takes no longer than the HBM reads would take beyond
+    VMEM reads.  Off a TPU (no VMEM) the answer is no."""
+    try:
+        info = pltpu.get_tpu_info()
+    except ValueError:  # not a TPU
+        return False
+    if sketch_table_vmem_bytes(table_bytes, lanes) > info.vmem_capacity_bytes:
+        return False
+    copy_s = table_bytes / info.mem_bw_bytes_per_second
+    return copy_s <= reads * HBM_ROW_EXTRA_S
 
 
 @functools.partial(jax.jit, static_argnames=("w",))
 def gather_sketches(table: jax.Array, pos: jax.Array, *, w: int) -> jax.Array:
-    """Candidate sketches from a blocked sketch table, word-major.
+    """Candidate sketches from an index's resident sketch table, word-major.
 
     Args:
-      table: (R, L) uint32 blocked table (``repro.core.sketch``).
+      table: (R, L) uint32 blocked table, or (n, w) rows
+        (``repro.core.sketch``).
       pos: (Q, K) int32 master positions.
       w: words per sketch.
 
@@ -108,16 +128,16 @@ def gather_sketches(table: jax.Array, pos: jax.Array, *, w: int) -> jax.Array:
       (Q', w, K') uint32 with Q' and K' padded to the row kernels' tiles;
       padded entries hold position 0's sketch.
 
-    A table of up to ``VMEM_TABLE_BYTES`` is copied whole into VMEM, and
-    the candidates' rows are read there (``sketch_table_kernel``).  A
-    larger one stays in HBM: XLA gathers one whole L-lane row per
-    candidate and ``sketch_slots_kernel`` keeps its slot.  On a v5e at
-    Table-1 shapes the first costs 2.0 ms a tree, the second 9.0.
+    A blocked table is read in VMEM (``sketch_table_kernel``) where
+    :func:`table_in_vmem` says so; otherwise XLA gathers one whole L-lane
+    row per candidate from HBM and ``sketch_slots_kernel`` keeps its slot.
     """
     bk = _rows_bk(pos.shape[1])
     pos = _pad_to(_pad_to(pos, ROWS_BQ, 0), bk, 1)
+    if not sketch.is_blocked(table, w):
+        return jnp.swapaxes(table[pos], 1, 2)
     s = sketch.slot_words(w)
-    if table.nbytes <= VMEM_TABLE_BYTES:
+    if table_in_vmem(table.nbytes, pos.size, table.shape[1]):
         rows, slots = sketch.row_and_slot(table, pos, w)
         return sketch_table_kernel(rows, slots, table, w=w, s=s, bk=bk)
     rows, slots = sketch.gather_rows(table, pos, w)

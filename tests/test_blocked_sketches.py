@@ -1,13 +1,15 @@
-"""The blocked sketch table that stage 1 reads.
+"""The blocked sketch table that stage 1 reads on a TPU.
 
-An index keeps its W-word sketches in S-word slots (S = the next power of
-two ≥ W), 128 / S to a 128-lane row, and stage 1 gathers whole rows and
-keeps each candidate's slot.  These tests pin that the read returns exactly
-the (n, W) rows it replaced, that search, graph construction and
-persistence give what the (n, W) table gave, and that the on-disk layout
-stays (n, W).
+An index on a TPU keeps its W-word sketches in S-word slots (S = the next
+power of two ≥ W), 128 / S to a 128-lane row, and stage 1 gathers whole
+rows and keeps each candidate's slot; elsewhere it keeps the (n, W) rows.
+These tests pin that the read returns exactly the (n, W) rows, that search,
+graph construction and persistence give the same on either layout (the
+blocked one forced here on the CPU), and that the on-disk layout stays
+(n, W).
 """
 
+import contextlib
 import json
 import os
 
@@ -46,10 +48,22 @@ def dataset():
     return jnp.asarray(data), jnp.asarray(queries)
 
 
+@contextlib.contextmanager
+def _layout(layout):
+    """Indexes built or loaded inside keep ``layout``: the TPU's blocked
+    table, or the (n, W) rows every other device keeps."""
+    with pytest.MonkeyPatch.context() as mp:
+        if layout == "blocked":
+            mp.setattr(sketch, "resident_table", sketch.block_sketches)
+        yield
+
+
 @pytest.fixture(scope="module")
 def index(dataset):
+    """The index as a TPU keeps it: a blocked sketch table."""
     data, _ = dataset
-    return HilbertIndex.build(data, CFG)
+    with _layout("blocked"):
+        return HilbertIndex.build(data, CFG)
 
 
 def _random_sketches(n, w, seed):
@@ -88,15 +102,14 @@ def test_blocked_read_returns_the_rows(n, w):
 
 @pytest.fixture(params=["vmem", "hbm"])
 def route(request, monkeypatch):
-    """The kernel route's two reads: a table held in VMEM, or rows gathered
-    from HBM (forced here by a zero VMEM budget)."""
-    if request.param == "vmem":
-        yield request.param
-        return
-    # Traced afresh under the zero budget; programs of either route must
+    """The kernel route's two reads of a blocked table: the table held in
+    VMEM, or rows gathered from HBM (the CPU has no VMEM, so the route is
+    forced here either way)."""
+    # Traced afresh under the forced route; programs of either route must
     # not outlive the fixture.
     jax.clear_caches()
-    monkeypatch.setattr(hamming_ops, "VMEM_TABLE_BYTES", 0)
+    monkeypatch.setattr(hamming_ops, "table_in_vmem",
+                        lambda *a: request.param == "vmem")
     yield request.param
     monkeypatch.undo()
     jax.clear_caches()
@@ -135,7 +148,47 @@ def test_index_keeps_one_blocked_table(index):
     view = np.asarray(index.sketches_view())
     assert view.shape == (N, w)
     rep = index.memory_report()
-    assert rep["sketch_bytes"] == index.sketches_master.nbytes
+    assert rep["sketch_table_bytes"] == index.sketches_master.nbytes
+    # the paper's model counts n·W words whatever the layout
+    assert rep["sketch_bytes"] == N * w * 4
+
+
+def test_resident_table_blocks_only_on_a_tpu(dataset, index):
+    """Off a TPU an (n, W) row is one contiguous read already, so the
+    index keeps the rows as they are, unpadded: the same rows the blocked
+    table holds."""
+    sk = jnp.asarray(_random_sketches(100, 12, seed=5))
+    assert jax.default_backend() != "tpu"
+    assert sketch.resident_table(sk) is sk
+    rows = HilbertIndex.build(dataset[0], CFG)
+    w = sketch.sketch_words(D)
+    assert rows.sketches_master.shape == (N, w)
+    np.testing.assert_array_equal(np.asarray(rows.sketches_master),
+                                  np.asarray(index.sketches_view()))
+    rep = rows.memory_report()
+    assert rep["sketch_table_bytes"] == rep["sketch_bytes"] == N * w * 4
+    assert not sketch.is_blocked(sk, 12)
+    assert sketch.is_blocked(sketch.block_sketches(sk), 12)
+    # at W = 128 the two layouts are one array
+    wide = jnp.asarray(_random_sketches(10, 128, seed=6))
+    np.testing.assert_array_equal(
+        np.asarray(sketch.block_sketches(wide)), np.asarray(wide))
+
+
+@pytest.mark.parametrize("w", [12, 128])
+def test_kernel_read_of_rows_returns_the_rows(w):
+    """gather_sketches on an (n, W) table: the plain row gather,
+    word-major and padded as from a blocked table."""
+    n = 300
+    sk = _random_sketches(n, w, seed=w)
+    pos = _positions(n, (7, 37), seed=w)
+    wm = np.asarray(hamming_ops.gather_sketches(jnp.asarray(sk),
+                                                jnp.asarray(pos), w=w))
+    assert wm.shape == (8, w, 128)
+    np.testing.assert_array_equal(wm[:7, :, :37], sk[pos].transpose(0, 2, 1))
+    np.testing.assert_array_equal(
+        np.asarray(sketch.read_sketches(jnp.asarray(sk), jnp.asarray(pos), w)),
+        sk[pos])
 
 
 def _unblocked_reference(index, queries, params):
@@ -167,7 +220,7 @@ def _unblocked_reference(index, queries, params):
 
 
 def test_search_matches_the_unblocked_table(dataset, index):
-    """Fused chunk and per-tree reference path on the blocked table give
+    """Fused chunk and per-tree reference path on the index's table give
     the (n, W) table's ids and distances, bit for bit."""
     _, queries = dataset
     ids_r, d2_r = _unblocked_reference(index, queries, SP)
@@ -225,7 +278,8 @@ def test_knn_graph_matches_the_unblocked_sketches(dataset, index):
 
 def test_save_load_keeps_the_disk_layout(tmp_path, dataset, index):
     """On disk the sketches stay (n, W) uint32, so checkpoints written
-    before the blocked table load unchanged; load blocks them again."""
+    before the blocked table load unchanged; load lays them out for the
+    device again."""
     _, queries = dataset
     path = str(tmp_path / "idx")
     index.save(path)
@@ -235,11 +289,16 @@ def test_save_load_keeps_the_disk_layout(tmp_path, dataset, index):
     assert manifest["extra"]["format_version"] == 2
     assert manifest["leaves"]["['sketches_master']"] == [
         [N, sketch.sketch_words(D)], "uint32"]
-    loaded = HilbertIndex.load(path)
+    with _layout("blocked"):
+        loaded = HilbertIndex.load(path)
     np.testing.assert_array_equal(
         np.asarray(loaded.sketches_master), np.asarray(index.sketches_master)
     )
+    # loaded here, off a TPU, the same checkpoint keeps (n, W) rows
+    rows = HilbertIndex.load(path)
+    assert rows.sketches_master.shape == (N, sketch.sketch_words(D))
     ids, d2 = index.search(queries, SP)
-    ids2, d22 = loaded.search(queries, SP)
-    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids2))
-    np.testing.assert_array_equal(np.asarray(d2), np.asarray(d22))
+    for other in (loaded, rows):
+        ids2, d22 = other.search(queries, SP)
+        np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids2))
+        np.testing.assert_array_equal(np.asarray(d2), np.asarray(d22))

@@ -311,14 +311,9 @@ def test_resident_bytes_drop_at_paper_dim():
     idx = HilbertIndex.build(jnp.asarray(data), cfg)
     rep = idx.memory_report()
     assert rep["points_bytes"] == 0
-    # The blocked sketch table pads each 12-word sketch to a 16-word slot;
-    # count the sketches at their (n, W) size on both sides, so the ratio
-    # measures the code packing alone.
-    resident = (rep["resident_bytes"] - rep["sketch_bytes"]
-                + n * sketch.sketch_words(d) * 4)
     # what the same index cost when codes were resident unpacked uint8
-    unpacked_baseline = resident - rep["codes_bytes"] + n * d
-    drop = 1.0 - resident / unpacked_baseline
+    unpacked_baseline = rep["resident_bytes"] - rep["codes_bytes"] + n * d
+    drop = 1.0 - rep["resident_bytes"] / unpacked_baseline
     assert drop >= 0.40, f"resident drop {drop:.1%} < 40%"
 
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import pubmed23
@@ -32,6 +33,9 @@ from repro.core import quantize
 from repro.core import search as search_lib
 from repro.index import IndexConfig
 from repro.kernels.hamming import hamming_rows
+from repro.kernels.hamming import ops as hamming_ops
+from repro.kernels.hamming.kernel import (sketch_table_kernel,
+                                          sketch_table_vmem_bytes)
 from repro.kernels.qdist import qdist_windows_from_packed
 
 D = pubmed23.DIM
@@ -42,8 +46,9 @@ ROW1 = pubmed23.TABLE1[0]   # k1=1420, k2=370, h=2, k=30
 N = 1 << 20
 QUERY_CHUNK = IndexConfig().query_chunk
 HBM_V5E = 16 << 30
-# memory_report()["resident_bytes"] of the 2^20-row pubmed23 index.  Its
-# sketch table is blocked: 12-word sketches in 16-word slots, 8 to a row.
+# memory_report()["resident_bytes"] of the 2^20-row pubmed23 index on a
+# TPU.  Its sketch table is blocked: 12-word sketches in 16-word slots, 8 to
+# a row (the device bytes an (n, 12) table takes there too).
 RESIDENT_2_20 = 2_652_837_888
 SKETCH_ROWS = N * 16 // 128
 
@@ -75,6 +80,16 @@ def one_chip(topo):
 
 def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _traced_for(sharding):
+    """Trace as for the sharding's chip: what the program asks of the chip
+    while tracing (``pltpu.get_tpu_info()``, e.g. its VMEM) is then the
+    described v5e's, not the host's."""
+    dev = next(iter(sharding.device_set))
+    return jax.sharding.use_abstract_mesh(jax.sharding.AbstractMesh(
+        (1,), ("chip",),
+        abstract_device=jax.sharding.AbstractDevice(dev.device_kind, 1)))
 
 
 def _compile(fn, *specs):
@@ -135,11 +150,13 @@ def _lower_search_chunk(sharding, use_kernels, *, n=N, trees=None,
         boundaries=_spec((D, 15), jnp.float32, s),
         centroids=_spec((D, 16), jnp.float32, s),
     )
-    return jax.jit(chunk).lower(
-        _spec((q, D), jnp.float32, s), forest,
-        _spec((n,), jnp.int32, s), _spec((n * 16 // 128, 128), jnp.uint32, s),
-        _spec((n, W_CODES), jnp.uint32, s), _spec((n,), jnp.int32, s), quant,
-    )
+    with _traced_for(s):
+        return jax.jit(chunk).lower(
+            _spec((q, D), jnp.float32, s), forest, _spec((n,), jnp.int32, s),
+            _spec((n * 16 // 128, 128), jnp.uint32, s),
+            _spec((n, W_CODES), jnp.uint32, s), _spec((n,), jnp.int32, s),
+            quant,
+        )
 
 
 def _compile_search_chunk(sharding, use_kernels):
@@ -213,24 +230,47 @@ def test_stage1_reads_whole_sketch_rows(pallas_chunk):
     assert operands[-1] == f"u32[{SKETCH_ROWS},128]{{1,0", operands
 
 
-def test_stage1_gathers_whole_rows_past_vmem(one_chip):
-    """A table larger than VMEM takes the HBM route: XLA gathers one whole
-    128-lane row of the row-major table per candidate, and the
-    ``sketch_slots`` kernel reads the gathered rows with no relayout."""
-    fcfg, s, q, n = pubmed23.FOREST, one_chip, QUERY_CHUNK, 2 * N
+def _lower_stage1(sharding, use_kernels, n):
+    """One tree's stage 1 at Table-1 shapes over a blocked table of ``n``
+    rows, compiled for the v5e; the optimized HLO text."""
+    fcfg, s, q = pubmed23.FOREST, sharding, QUERY_CHUNK
     n_dir = -(-n // fcfg.leaf_size)
     key_w = -(-fcfg.key_bits // 32)
-    text = jax.jit(lambda *a: search_lib.stage1_tree_merge(
-        *a, bits=fcfg.bits, key_bits=fcfg.key_bits,
-        leaf_size=fcfg.leaf_size, k1=ROW1.k1, k2=ROW1.k2, use_kernels=True,
-    )).lower(
-        _spec((q, D), jnp.float32, s), _spec((q, W_SKETCH), jnp.uint32, s),
-        _spec((q, ROW1.k2), jnp.int32, s), _spec((q, ROW1.k2), jnp.int32, s),
-        _spec((n,), jnp.int32, s), _spec((n_dir, key_w), jnp.uint32, s),
-        _spec((D,), jnp.float32, s), _spec((D,), jnp.float32, s),
-        _spec((D,), jnp.int32, s), _spec((D,), jnp.bool_, s),
-        _spec((n,), jnp.int32, s), _spec((n * 16 // 128, 128), jnp.uint32, s),
-    ).compile().as_text()
+    with _traced_for(s):
+        lowered = jax.jit(lambda *a: search_lib.stage1_tree_merge(
+            *a, bits=fcfg.bits, key_bits=fcfg.key_bits,
+            leaf_size=fcfg.leaf_size, k1=ROW1.k1, k2=ROW1.k2,
+            use_kernels=use_kernels,
+        )).lower(
+            _spec((q, D), jnp.float32, s), _spec((q, W_SKETCH), jnp.uint32, s),
+            _spec((q, ROW1.k2), jnp.int32, s),
+            _spec((q, ROW1.k2), jnp.int32, s),
+            _spec((n,), jnp.int32, s), _spec((n_dir, key_w), jnp.uint32, s),
+            _spec((D,), jnp.float32, s), _spec((D,), jnp.float32, s),
+            _spec((D,), jnp.int32, s), _spec((D,), jnp.bool_, s),
+            _spec((n,), jnp.int32, s),
+            _spec((n * 16 // 128, 128), jnp.uint32, s),
+        )
+    return lowered.compile().as_text()
+
+
+def test_xla_slot_select_relays_rows_out(one_chip):
+    """Why the HBM route keeps its slots in a Pallas kernel: XLA's own
+    select of the slot (the XLA route) copies the gathered rows, one
+    128-lane row per candidate, into another layout."""
+    text = _lower_stage1(one_chip, False, 2 * N)
+    copies = re.findall(r"= (\S+) copy\(", text)
+    assert [c for c in copies
+            if _elements(c) >= QUERY_CHUNK * ROW1.k1 * 128], copies
+
+
+def test_stage1_gathers_whole_rows_past_vmem(one_chip):
+    """A table too large for VMEM (2^21 rows, 128 MiB) takes the HBM
+    route: XLA gathers one whole 128-lane row of the row-major table per
+    candidate, and the ``sketch_slots`` kernel reads the gathered rows with
+    no relayout (XLA's own slot select relays them out)."""
+    q, n = QUERY_CHUNK, 2 * N
+    text = _lower_stage1(one_chip, True, n)
     gathers = _assert_no_point_minor_read(text, q * ROW1.k1 * 128)
     rows = [op for op, sizes in gathers if sizes == "1,128"]
     assert rows
@@ -238,6 +278,32 @@ def test_stage1_gathers_whole_rows_past_vmem(one_chip):
         assert _hlo_shape(text, op).startswith(
             f"u32[{n * 16 // 128},128]{{1,0"), _hlo_shape(text, op)
     assert _kernel_signature(text, "sketch_slots")[0] == "u32"
+
+
+def test_vmem_route_follows_the_chip_and_the_reads(one_chip):
+    """The table is read in VMEM only where it fits the chip's VMEM beside
+    the kernel's blocks and its copy costs less than the HBM route's extra
+    time per row; the largest table admitted compiles for the v5e."""
+    table = N * 16 // 128 * 128 * 4             # Table-1 table, 64 MiB
+    assert not hamming_ops.table_in_vmem(table, QUERY_CHUNK * 1536, 128)
+    with _traced_for(one_chip):
+        vmem = pltpu.get_tpu_info().vmem_capacity_bytes
+        assert hamming_ops.table_in_vmem(table, QUERY_CHUNK * 1536, 128)
+        assert not hamming_ops.table_in_vmem(2 * table, QUERY_CHUNK * 1536,
+                                             128)
+        # too few reads to pay for the copy
+        assert not hamming_ops.table_in_vmem(table, 64, 128)
+        rows = (vmem - sketch_table_vmem_bytes(0, 128)) // 512
+        assert hamming_ops.table_in_vmem(rows * 512, QUERY_CHUNK * 1536, 128)
+        assert not hamming_ops.table_in_vmem((rows + 1) * 512,
+                                             QUERY_CHUNK * 1536, 128)
+    _compile(
+        lambda r, sl, t: sketch_table_kernel(r, sl, t, w=W_SKETCH, s=16,
+                                             bk=512),
+        _spec((8, 1536), jnp.int32, one_chip),
+        _spec((8, 1536), jnp.int32, one_chip),
+        _spec((rows, 128), jnp.uint32, one_chip),
+    )
 
 
 def test_fused_search_chunk_xla_route_fits_v5e(one_chip):
