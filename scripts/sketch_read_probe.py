@@ -12,8 +12,9 @@ jitted scan and prints, per route, the time per tree of five repeats:
 * ``hbm_xla`` — XLA's whole-row gather and its own slot select.
 
 Each route's distances are checked against ``plain``'s.  The last line
-gives the best time per tree of each; ``ops.HBM_ROW_EXTRA_S`` is read from
-these (``hbm_slots`` less ``vmem``, per candidate row).
+gives the best time per tree of each; ``ops.ROW_READ_SAVING_S`` is read
+from these: the rows read at which ``vmem`` and ``hbm_slots`` break even,
+over the table's copy time at the chip's peak HBM bandwidth.
 """
 
 import json

@@ -24,9 +24,11 @@ from repro.kernels.hamming.kernel import (BC, BQ, ROWS_BK, ROWS_BQ,
 from repro.kernels.hamming.ref import hamming_matrix_ref
 
 
-# Time a candidate row read from HBM (XLA's row gather and the slot
-# select) takes beyond one read from a table held in VMEM, on a v5e.
-HBM_ROW_EXTRA_S = 8.8e-9
+# What the VMEM route saves per candidate row read, net of its costs beyond
+# copying the table at the chip's peak HBM bandwidth.  On a v5e with a
+# 64 MiB table the two routes break even near 13.8k rows read, between 8
+# and 64 queries of 1,420 candidates (scripts/sketch_read_probe.py).
+ROW_READ_SAVING_S = 5.9e-9
 
 
 def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
@@ -102,8 +104,8 @@ def table_in_vmem(table_bytes: int, reads: int, lanes: int) -> bool:
     """Whether stage 1 reads ``reads`` candidate rows from a blocked table
     of ``table_bytes`` copied into VMEM, rather than gathering them from
     HBM: the table fits the chip's VMEM beside the kernel's blocks, and
-    copying it in takes no longer than the HBM reads would take beyond
-    VMEM reads.  Off a TPU (no VMEM) the answer is no."""
+    the rows read save more than copying it in costs.  Off a TPU (no
+    VMEM) the answer is no."""
     try:
         info = pltpu.get_tpu_info()
     except ValueError:  # not a TPU
@@ -111,7 +113,7 @@ def table_in_vmem(table_bytes: int, reads: int, lanes: int) -> bool:
     if sketch_table_vmem_bytes(table_bytes, lanes) > info.vmem_capacity_bytes:
         return False
     copy_s = table_bytes / info.mem_bw_bytes_per_second
-    return copy_s <= reads * HBM_ROW_EXTRA_S
+    return copy_s <= reads * ROW_READ_SAVING_S
 
 
 @functools.partial(jax.jit, static_argnames=("w",))
